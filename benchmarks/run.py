@@ -229,4 +229,6 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     main()

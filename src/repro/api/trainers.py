@@ -223,6 +223,12 @@ class PopulationTrainer:
                                      c.obs)
         self._init = lambda: population_init(init_one, self.seeds)
         mesh = replica_mesh(spec.seeds)
+        # with a replica mesh, init builds each replica on its own device
+        # (unsharded, the whole P-stack would land on device 0)
+        self._carry_sharding = (None if mesh is None else
+                                jax.sharding.NamedSharding(
+                                    mesh, jax.sharding.PartitionSpec(
+                                        "replica")))
         self.cycle = jax.jit(make_population_cycle(
             c.env, c.qf, c.opt, c.dcfg, obs=c.obs,
             kernel_backend=spec.exec.kernel_backend, q_logits=c.qlog,
@@ -236,7 +242,7 @@ class PopulationTrainer:
         # the replica seeds fully determine every RNG stream; ``key`` is
         # accepted for protocol uniformity and must be None
         assert key is None, "population init derives all RNG from seeds"
-        return jax.jit(self._init)()
+        return jax.jit(self._init, out_shardings=self._carry_sharding)()
 
     def init_template(self) -> TrainerCarry:
         return jax.eval_shape(self._init)

@@ -29,7 +29,7 @@ Launchers and benchmarks construct this layer through the
 are the mechanism, the spec is the interface.
 
 When several devices are visible, the replica axis is sharded over a
-1-D ``replica`` mesh via the ``repro.compat`` shard_map shim — each
+1-D ``replica`` mesh with ``jax.shard_map`` — each
 device advances P/D replicas with zero cross-device communication (the
 replicas are independent by construction, so the program partitions
 embarrassingly).
@@ -42,7 +42,6 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.config import DQNConfig
 from repro.core.concurrent import (EVAL_STREAM_TAG, TrainerCarry,
                                    make_concurrent_cycle, prepopulate,
@@ -136,8 +135,8 @@ def replica_mesh(n_replicas: int, devices: Optional[Sequence] = None):
         d -= 1
     if d <= 1:
         return None
-    return compat.make_mesh(
-        (d,), ("replica",),
+    return jax.make_mesh(
+        (d,), ("replica",), axis_types=(jax.sharding.AxisType.Auto,),
         devices=None if devices is None else list(devices)[:d])
 
 
@@ -159,11 +158,11 @@ def make_population_cycle(spec: EnvSpec, q_forward: Callable, opt,
                                   kernel_backend=kernel_backend,
                                   q_logits=q_logits)
     vcycle = jax.vmap(cycle)
-    if mesh is None or compat.mesh_is_empty(mesh):
+    if mesh is None:
         return vcycle
     pspec = jax.sharding.PartitionSpec("replica")
-    return compat.shard_map(vcycle, mesh=mesh, in_specs=pspec,
-                            out_specs=pspec, check_vma=False)
+    return jax.shard_map(vcycle, mesh=mesh, in_specs=pspec,
+                         out_specs=pspec, check_vma=False)
 
 
 def eval_keys(seeds: jax.Array, step) -> jax.Array:

@@ -3,9 +3,8 @@
 Layout:
   backend.py  — kernel registry and trace-time backend resolution
                 (mosaic | triton | interpret | ref), env override via
-                ``REPRO_KERNEL_BACKEND``.
-  compat.py   — Pallas API shims across JAX versions (the
-                CompilerParams/TPUCompilerParams rename and friends).
+                ``REPRO_KERNEL_BACKEND``; per-backend Pallas compiler
+                params.
   ref.py      — pure-XLA oracles; the semantics contract for every op
                 and the always-available fallback backend.
   ops.py      — public model-facing wrappers (layout transposes,

@@ -27,17 +27,20 @@ precedence:
 Logical requests (``auto`` / ``pallas``) map to a concrete backend via
 ``jax.default_backend()``; a concrete backend with no registered
 implementation for an op falls back to ``ref`` (e.g. the sequential
-``slstm_scan`` has no Triton lowering), so dispatch never hard-fails on
-a missing kernel — the XLA oracle is always executable.
+``slstm_scan`` has no Triton lowering). On a TPU both DQN ops
+(``segment_tree``, ``categorical_projection``) resolve to ``mosaic``;
+``chip_smoke.py`` fails if they do not.
 """
 
 from __future__ import annotations
 
 import functools
 import os
-from typing import Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
+from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas import triton as pltriton
 
 MOSAIC = "mosaic"
 TRITON = "triton"
@@ -157,3 +160,25 @@ def testable_backends(op: str) -> Tuple[str, ...]:
             continue
         out.append(b)
     return tuple(out)
+
+
+def compiler_params(backend: str, *, interpret: bool = False,
+                    dimension_semantics: Optional[tuple] = None,
+                    num_warps: Optional[int] = None,
+                    num_stages: Optional[int] = None) -> Any:
+    """Pallas compiler params for ``backend`` ('mosaic' | 'triton'), or
+    ``None`` in interpret mode (the interpreter ignores them).
+
+    TPU-only knobs (``dimension_semantics``) and GPU-only knobs
+    (``num_warps`` / ``num_stages``) are filtered to the matching backend,
+    so a kernel shared by both schedules can declare both.
+    """
+    if interpret:
+        return None
+    if backend == MOSAIC:
+        return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
+    if backend == TRITON:
+        kw = {k: v for k, v in (("num_warps", num_warps),
+                                ("num_stages", num_stages)) if v is not None}
+        return pltriton.CompilerParams(**kw)
+    raise ValueError(f"no compiler params for backend {backend!r}")

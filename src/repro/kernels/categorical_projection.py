@@ -43,7 +43,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels import backend as kb
-from repro.kernels import compat
 from repro.kernels.segment_tree import next_pow2
 
 
@@ -89,7 +88,9 @@ def _proj_kernel(p_ref, r_ref, d_ref, o_ref, *, K: int, v_min: float,
     p = p_ref[...].astype(jnp.float32)                      # (bb, Kp)
     r = r_ref[...].astype(jnp.float32)                      # (bb, 1)
     d = d_ref[...].astype(jnp.float32)
-    i_lane = jax.lax.broadcasted_iota(jnp.float32, p.shape, 1)
+    # Mosaic's iota is integer-only: build it as int32, then cast
+    i_lane = jax.lax.broadcasted_iota(jnp.int32, p.shape, 1).astype(
+        jnp.float32)
     o_ref[...] = _hat_accumulate(p, r, d, i_lane, K=K, v_min=v_min,
                                  v_max=v_max, gamma_n=gamma_n, delta=delta)
 
@@ -123,7 +124,7 @@ def categorical_projection_kernel(probs: jax.Array, rewards: jax.Array,
         ],
         out_specs=pl.BlockSpec((bb, Kp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Bp, Kp), jnp.float32),
-        compiler_params=compat.compiler_params(
+        compiler_params=kb.compiler_params(
             kb.MOSAIC, interpret=interpret, dimension_semantics=("parallel",)),
         interpret=interpret,
     )(p, r, d)
@@ -174,7 +175,7 @@ def categorical_projection_kernel_gpu(probs: jax.Array, rewards: jax.Array,
         ],
         out_specs=pl.BlockSpec((tb, Kp2), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Bp, Kp2), jnp.float32),
-        compiler_params=compat.compiler_params(
+        compiler_params=kb.compiler_params(
             kb.TRITON, interpret=interpret, num_warps=4, num_stages=2),
         interpret=interpret,
     )(p, r, d)

@@ -25,7 +25,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import backend as kb
-from repro.kernels import compat
 
 MASK_VALUE = float("-inf")
 M_INIT = -1e30
@@ -88,7 +87,7 @@ def decode_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
     kernel = functools.partial(_decode_kernel, scale=scale, bl=bl, n_l_blocks=n_l)
     q4 = q[:, :, None, :]                                  # (B, H, 1, D)
 
-    grid_spec = compat.prefetch_scalar_grid_spec(
+    grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, H, n_l),
         in_specs=[
@@ -107,7 +106,7 @@ def decode_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
-        compiler_params=compat.compiler_params(
+        compiler_params=kb.compiler_params(
             kb.MOSAIC, interpret=interpret,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
@@ -180,7 +179,7 @@ def decode_attention_kernel_gpu(q: jax.Array, k: jax.Array, v: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, 1, 1, D), lambda b, h: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
-        compiler_params=compat.compiler_params(
+        compiler_params=kb.compiler_params(
             kb.TRITON, interpret=interpret, num_warps=4, num_stages=2),
         interpret=interpret,
     )(cache_len.reshape(1).astype(jnp.int32), q4, k, v)

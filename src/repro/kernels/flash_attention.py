@@ -32,7 +32,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import backend as kb
-from repro.kernels import compat
 
 MASK_VALUE = float("-inf")
 M_INIT = -1e30
@@ -130,7 +129,7 @@ def flash_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 128), jnp.float32),   # l
             pltpu.VMEM((bq, D), jnp.float32),     # acc
         ],
-        compiler_params=compat.compiler_params(
+        compiler_params=kb.compiler_params(
             kb.MOSAIC, interpret=interpret,
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
@@ -218,7 +217,7 @@ def flash_attention_kernel_gpu(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, qi: (b, h, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-        compiler_params=compat.compiler_params(
+        compiler_params=kb.compiler_params(
             kb.TRITON, interpret=interpret, num_warps=4, num_stages=2),
         interpret=interpret,
     )(q, k, v)

@@ -7,8 +7,8 @@ VMEM per step at block_rows=256, D=8192, bf16: 4 MiB in + 4 MiB out.
 
 The kernel body is backend-neutral — no scratch, no scalar memory, a
 fully parallel grid — so the same ``pallas_call`` lowers through Mosaic
-on TPU and Triton on GPU; only the compiler params differ (built via
-``kernels/compat.py``).
+on TPU and Triton on GPU; only the compiler params differ (built by
+``backend.compiler_params``).
 """
 
 from __future__ import annotations
@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels import backend as kb
-from repro.kernels import compat
 
 
 def _rmsnorm_kernel(x_ref, g_ref, o_ref, *, eps: float):
@@ -52,7 +51,7 @@ def rmsnorm_kernel(x: jax.Array, gamma: jax.Array, *, eps: float = 1e-5,
         ],
         out_specs=pl.BlockSpec((br, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, D), x.dtype),
-        compiler_params=compat.compiler_params(
+        compiler_params=kb.compiler_params(
             backend, interpret=interpret,
             dimension_semantics=("parallel",), num_warps=4),
         interpret=interpret,

@@ -13,10 +13,15 @@ over the leaf level: idx(t) = #{i : cumsum_i <= t}, computed blockwise in
 one pass over the leaf array (exactly the flash-decoding pattern already
 used by ``decode_attention``):
 
-TPU Mosaic — grid over leaf blocks (innermost, sequential); the running
-prefix offset and per-target hit counts ride in VMEM scratch; the (n,)
-query batch stays resident across steps. VMEM per step at bl=1024:
-4 KiB of leaves + the (N, bl) compare tile ≈ 0.5 MiB at N=128.
+TPU Mosaic — grid over leaf blocks (innermost, sequential). A block of
+bl leaves is laid out as (bl/128, 128) so each row is one lane vector;
+the in-row prefix sum is a log-step shift-and-add with ``pltpu.roll``
+(Mosaic has no ``cumsum``), and rows are chained through a running
+offset. The offset and per-target, per-lane hit counts ride in VMEM
+scratch; the (n,) query batch stays resident across steps. VMEM per
+step at bl=1024: 4 KiB of leaves, and one (N, 128) compare tile per
+row plus the (N, 128) count scratch, 16 KiB each at N=32 (64 KiB at
+N=128) — far below the 16 MiB scoped-VMEM default of a v5e.
 
 GPU Triton — grid over target blocks, one program per 128 queries; the
 leaf array is walked with an on-chip ``fori_loop``; (offset, counts)
@@ -38,7 +43,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import backend as kb
-from repro.kernels import compat
 
 
 def next_pow2(n: int) -> int:
@@ -66,7 +70,18 @@ def tree_build(priority: jax.Array) -> jax.Array:
 # TPU Mosaic schedule
 # ---------------------------------------------------------------------------
 
-def _seg_kernel(leaf_ref, t_ref, o_ref, cnt_scr, off_scr, *, bl: int,
+def _lane_prefix_sum(x: jax.Array) -> jax.Array:
+    """Inclusive prefix sum along the 128-lane axis of ``x`` (rows, 128):
+    Hillis-Steele shift-and-add, log2(128) = 7 rolls."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    shift = 1
+    while shift < x.shape[1]:
+        x = x + jnp.where(lane >= shift, pltpu.roll(x, shift, 1), 0.0)
+        shift *= 2
+    return x
+
+
+def _seg_kernel(leaf_ref, t_ref, o_ref, cnt_scr, off_scr, *, rows: int,
                 n_blocks: int, max_idx: int):
     i = pl.program_id(0)
 
@@ -75,20 +90,23 @@ def _seg_kernel(leaf_ref, t_ref, o_ref, cnt_scr, off_scr, *, bl: int,
         cnt_scr[...] = jnp.zeros_like(cnt_scr)
         off_scr[...] = jnp.zeros_like(off_scr)
 
-    leaves = leaf_ref[...].astype(jnp.float32)            # (1, bl)
-    cum = off_scr[0, 0] + jnp.cumsum(leaves, axis=1)      # (1, bl)
+    leaves = leaf_ref[...].astype(jnp.float32)            # (rows, 128)
+    cum = _lane_prefix_sum(leaves)                        # in-row prefix
+    row_mass = jnp.sum(leaves, axis=1, keepdims=True)     # (rows, 1)
     t = t_ref[...].astype(jnp.float32)                    # (N, 1)
-    N = t.shape[0]
-    hits = (jax.lax.broadcast_in_dim(cum, (N, bl), (0, 1))
-            <= jax.lax.broadcast_in_dim(t, (N, bl), (0, 1)))
-    cnt_scr[...] = cnt_scr[...] + jax.lax.broadcast_in_dim(
-        jnp.sum(hits.astype(jnp.float32), axis=1, keepdims=True),
-        cnt_scr.shape, (0, 1))
-    off_scr[...] = off_scr[...] + jnp.sum(leaves)
+    off = off_scr[0:1, :]                                 # (1, 128)
+    hits = cnt_scr[...]                                   # (N, 128)
+    for r in range(rows):
+        hits = hits + (off + cum[r:r + 1, :] <= t).astype(jnp.float32)
+        off = off + row_mass[r:r + 1, :]
+    cnt_scr[...] = hits
+    off_scr[0:1, :] = off
 
     @pl.when(i == n_blocks - 1)
     def _finalize():
-        o_ref[...] = jnp.minimum(cnt_scr[...], max_idx).astype(jnp.int32)
+        cnt = jnp.sum(cnt_scr[...], axis=1, keepdims=True)   # (N, 1)
+        o_ref[...] = jnp.broadcast_to(
+            jnp.minimum(cnt, max_idx).astype(jnp.int32), o_ref.shape)
 
 
 @kb.register("segment_tree", kb.MOSAIC)
@@ -104,6 +122,7 @@ def segment_tree_kernel(tree: jax.Array, targets: jax.Array, *,
     if L > P:
         leaves = jnp.pad(leaves, (0, L - P))
     bl = min(block, L)                                # both powers of two
+    rows = bl // 128
     n_blocks = L // bl
     n = targets.shape[0]
     N = max(-(-n // 8) * 8, 8)                        # sublane-pad queries
@@ -111,25 +130,25 @@ def segment_tree_kernel(tree: jax.Array, targets: jax.Array, *,
     if N > n:
         t = jnp.pad(t, (0, N - n), constant_values=-1.0)   # count 0, sliced
 
-    kernel = functools.partial(_seg_kernel, bl=bl, n_blocks=n_blocks,
+    kernel = functools.partial(_seg_kernel, rows=rows, n_blocks=n_blocks,
                                max_idx=P - 1)
     out = pl.pallas_call(
         kernel,
         grid=(n_blocks,),
         in_specs=[
-            pl.BlockSpec((1, bl), lambda i: (0, i)),
+            pl.BlockSpec((rows, 128), lambda i: (i, 0)),
             pl.BlockSpec((N, 1), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((N, 128), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((N, 128), jnp.int32),
         scratch_shapes=[
-            pltpu.VMEM((N, 128), jnp.float32),        # per-target hit counts
-            pltpu.VMEM((8, 128), jnp.float32),        # running prefix offset
+            pltpu.VMEM((N, 128), jnp.float32),        # per-target, per-lane hits
+            pltpu.VMEM((8, 128), jnp.float32),        # running offset, row 0
         ],
-        compiler_params=compat.compiler_params(
+        compiler_params=kb.compiler_params(
             kb.MOSAIC, interpret=interpret, dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(leaves.reshape(1, L), t.reshape(N, 1))
+    )(leaves.reshape(L // 128, 128), t.reshape(N, 1))
     return out[:n, 0]
 
 
@@ -185,7 +204,7 @@ def segment_tree_kernel_gpu(tree: jax.Array, targets: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((tb,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((NT,), jnp.int32),
-        compiler_params=compat.compiler_params(
+        compiler_params=kb.compiler_params(
             kb.TRITON, interpret=interpret, num_warps=4, num_stages=2),
         interpret=interpret,
     )(leaves, t)

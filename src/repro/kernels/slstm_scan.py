@@ -26,7 +26,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import backend as kb
-from repro.kernels import compat
 
 
 def _slstm_kernel(wx_ref, r_ref, b_ref, c0_ref, n0_ref, h0_ref, m0_ref,
@@ -121,7 +120,7 @@ def slstm_scan_kernel(wx: jax.Array, R: jax.Array, b: jax.Array,
             sstate, sstate, sstate, sstate,
         ],
         scratch_shapes=[pltpu.VMEM((B, d), jnp.float32)] * 4,
-        compiler_params=compat.compiler_params(
+        compiler_params=kb.compiler_params(
             kb.MOSAIC, interpret=interpret,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,

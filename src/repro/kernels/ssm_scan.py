@@ -26,7 +26,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import backend as kb
-from repro.kernels import compat
 
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, h_out_ref, h_scr,
@@ -107,7 +106,7 @@ def ssm_scan_kernel(x: jax.Array, dt: jax.Array, A: jax.Array,
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=compat.compiler_params(
+        compiler_params=kb.compiler_params(
             kb.MOSAIC, interpret=interpret,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
@@ -188,7 +187,7 @@ def ssm_scan_kernel_gpu(x: jax.Array, dt: jax.Array, A: jax.Array,
             jax.ShapeDtypeStruct((B, H, S, P), x.dtype),
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
-        compiler_params=compat.compiler_params(
+        compiler_params=kb.compiler_params(
             kb.TRITON, interpret=interpret, num_warps=4, num_stages=1),
         interpret=interpret,
     )(x, dt, A.astype(jnp.float32), Bm, Cm)

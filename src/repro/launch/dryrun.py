@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.config import ExecConfig, INPUT_SHAPES, TrainConfig
 from repro.configs import ARCH_IDS, get_config
 from repro.launch.mesh import make_production_mesh
@@ -61,7 +60,7 @@ def lower_one(arch: str, shape_name: str, multi_pod: bool,
         "n_chips": 512 if multi_pod else 256,
     }
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         pshard = param_shardings(cfg, mesh, ec)
         t0 = time.time()
         if shape.kind == "train":
@@ -114,7 +113,7 @@ def lower_one(arch: str, shape_name: str, multi_pod: bool,
     hlo = analyze_text(compiled.as_text())
     rec["flops_per_device"] = hlo["flops"]
     rec["bytes_per_device"] = hlo["bytes"]
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     rec["builtin_flops_unrolled_once"] = float(ca.get("flops", 0.0))
     ma = compiled.memory_analysis()
     if ma is not None:

@@ -11,16 +11,20 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat
+
+def _auto_mesh(shape, axes) -> jax.sharding.Mesh:
+    """A mesh whose axes are all Auto (the sharding rules rely on it)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1) -> jax.sharding.Mesh:
     """Tiny mesh over the actually-present devices (tests / examples)."""
     n = jax.device_count()
-    return compat.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))

@@ -65,6 +65,7 @@ import jax.numpy as jnp
 from repro.api import (ExperimentSpec, SpecCompatError, SweepSpec,
                        build_trainer, check_resume_compat, load_run_spec,
                        run_sweep, save_run_spec)
+from repro import compile_cache
 from repro.api.spec import MODES
 from repro.configs.dqn_nature import VARIANTS, get_variant
 from repro.checkpoint import (latest_step, restore_latest, save_checkpoint,
@@ -413,4 +414,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()      # the CLI only; in-process callers choose
     raise SystemExit(main())

@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import argparse
 
+from repro import compile_cache
 from repro.api import ExperimentSpec, ServeSpec, POLICIES
 from repro.api.policy_client import SimulatedClients, drive
 from repro.api.serve import load_policy, make_server
@@ -130,4 +131,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()      # the CLI only; in-process callers choose
     raise SystemExit(main())

@@ -17,7 +17,6 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.config import TrainConfig
 from repro.configs import get_config, reduced_config
 from repro.data import SyntheticLM
@@ -59,7 +58,7 @@ def main(argv=None):
     data = SyntheticLM(cfg.vocab, args.seq, args.batch)
     step_fn, opt = make_train_step(cfg, ec, tc)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = T.init_params(cfg, jax.random.PRNGKey(0), ec)
         opt_state = opt.init(params)
         pshard = param_shardings(cfg, mesh, ec)

@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.config import ModelConfig, MoEConfig
 from repro.config import ExecConfig
 from repro.models import params as PM
@@ -125,7 +124,7 @@ def _expert_parallel_moe(p, x, cfg: ModelConfig, m: MoEConfig):
     (pod?, data) and replicated over `model`; expert stacks sharded over
     `model`. Each rank dispatches to its local experts only and a single
     psum over `model` combines partial outputs."""
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     axes = mesh.axis_names
     bspec = tuple(a for a in ("pod", "data") if a in axes)
     B, S, d = x.shape
@@ -163,7 +162,7 @@ def _expert_parallel_moe(p, x, cfg: ModelConfig, m: MoEConfig):
             aux = jax.lax.pmean(aux, bspec)
         return y, aux
 
-    y, aux = compat.shard_map(
+    y, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(bspec, None, None), P(None, None),
                   P("model", None, None), P("model", None, None),
@@ -182,8 +181,8 @@ def moe_ffn(p, x: jax.Array, cfg: ModelConfig, ec: ExecConfig) -> Tuple[jax.Arra
 
     ep_ok = False
     if ec.moe_impl == "expert_parallel":
-        mesh = compat.get_abstract_mesh()
-        ep_ok = (not compat.mesh_is_empty(mesh)
+        mesh = jax.sharding.get_abstract_mesh()
+        ep_ok = (not mesh.empty
                  and "model" in mesh.axis_names
                  and padded_experts(m) % mesh.shape["model"] == 0)
     if ep_ok:

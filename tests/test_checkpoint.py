@@ -7,7 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.checkpoint import (latest_step, list_steps, restore_checkpoint,
                               restore_latest, save_checkpoint)
 from repro.core.concurrent import TrainerCarry
@@ -239,7 +238,7 @@ def test_restore_onto_shardings(tmp_path):
     tree = {"w": jnp.arange(8.0)}
     d = str(tmp_path / "ckpt")
     save_checkpoint(d, 1, tree)
-    mesh = compat.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",))
     sh = {"w": jax.sharding.NamedSharding(
         mesh, jax.sharding.PartitionSpec("data"))}
     got = restore_checkpoint(d, 1, tree, shardings=sh)

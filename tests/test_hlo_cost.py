@@ -6,7 +6,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.roofline.hlo_cost import HloCostModel, analyze_text, _parse_assign
 from repro.roofline.analysis import roofline_terms, HW
 
@@ -20,7 +19,7 @@ def test_matches_builtin_on_loop_free():
     w = jax.ShapeDtypeStruct((256, 512), jnp.float32)
     c = _compile(lambda x, w: x @ w, x, w)
     ours = analyze_text(c.as_text())["flops"]
-    builtin = compat.cost_analysis(c)["flops"]
+    builtin = c.cost_analysis()["flops"]
     np.testing.assert_allclose(ours, builtin, rtol=1e-6)
 
 
@@ -39,8 +38,7 @@ def test_scan_multiplied_by_trip_count():
     f1 = analyze_text(c1.as_text())["flops"]
     assert abs(f8 / f1 - 8.0) < 0.01
     # builtin undercounts: documents why the walker exists
-    assert (compat.cost_analysis(c8)["flops"]
-            == compat.cost_analysis(c1)["flops"])
+    assert c8.cost_analysis()["flops"] == c1.cost_analysis()["flops"]
 
 
 def test_nested_scan():

@@ -47,7 +47,6 @@ import os
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
 from repro.configs import reduced_config
 from repro.models import moe as M
 from repro.models import params as PM
@@ -61,8 +60,8 @@ cfg = reduced_config("qwen2-moe-a2.7b")
 cfg = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=8.0))
 p = PM.init_tree(M.moe_param_spec(cfg), jax.random.PRNGKey(0))
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model))
-mesh = compat.make_mesh((4, 2), ("data", "model"))
-with compat.set_mesh(mesh):
+mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
+with jax.set_mesh(mesh):
     y_ep, _ = jax.jit(lambda p, x: M.moe_ffn(p, x, cfg, ExecConfig(moe_impl="expert_parallel")))(p, x)
 y_dn, _ = M.moe_ffn(p, x, cfg, ExecConfig(moe_impl="dense"))
 err = float(jnp.abs(y_ep - y_dn).max())
@@ -71,7 +70,7 @@ assert err < 1e-4, err
 def loss(p):
     y, aux = M.moe_ffn(p, x, cfg, ExecConfig(moe_impl="expert_parallel"))
     return jnp.sum(y ** 2) + aux
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     g = jax.jit(jax.grad(loss))(p)
 assert all(bool(jnp.isfinite(v).all()) for v in jax.tree_util.tree_leaves(g))
 print("OK")

@@ -81,7 +81,6 @@ import os
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import json
 import jax, jax.numpy as jnp
-from repro import compat
 from repro.config import TrainConfig
 from repro.configs import reduced_config
 from repro.config import ExecConfig
@@ -97,8 +96,8 @@ if jax.device_count() < 8:
 
 cfg = reduced_config("granite-3-8b")
 ec = ExecConfig(remat=True)
-mesh = compat.make_mesh((2, 4), ("data", "model"))
-with compat.set_mesh(mesh):
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
+with jax.set_mesh(mesh):
     step, opt = make_train_step(cfg, ec, TrainConfig())
     params, opt_state = abstract_train_state(cfg, ec, TrainConfig())
     pshard = param_shardings(cfg, mesh, ec)
