@@ -1,0 +1,12 @@
+"""learn_ms (ms): device ms per cycle in the ``learn`` scope, the trainer
+scan of C/F minibatch updates (replay draw, forward, backward, optimizer).
+Nested scopes included; an op without a scope of its own takes its
+enclosing loop's (``bench/scopes.py``). Mean over the traced cycles
+and the cell's chips."""
+
+from bench import scopes
+
+
+def read(ctx):
+    secs = scopes.per_cycle_s(ctx, "learn")
+    return None if secs is None else 1e3 * secs

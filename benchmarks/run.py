@@ -12,7 +12,7 @@ Two section tiers (the ``--sections`` grammar accepts names from both):
 
 * **SECTIONS** (the default set) — every section whose rows fold into
   the committed ``BENCH_<n>.json`` trajectory: ``env_throughput``,
-  ``serve_policy``, ``cycle_time``, ``per_ops``, ``trace_overhead``.
+  ``serve_policy``, ``cycle_time``, ``per_ops``.
 * **LEGACY_SECTIONS** — the original paper-table reproductions
   (``table1``, ``transactions``, ``table4``, ``roofline``, ``perf``).
   They print their human-readable tables and contribute CSV rows, but
@@ -40,8 +40,7 @@ import json
 import sys
 
 # The recorded trajectory (default set): rows comparable across PRs.
-SECTIONS = ("env_throughput", "serve_policy", "cycle_time", "per_ops",
-            "trace_overhead")
+SECTIONS = ("env_throughput", "serve_policy", "cycle_time", "per_ops")
 # Paper-table reproductions: printable, row-emitting, but not recorded.
 LEGACY_SECTIONS = ("table1", "transactions", "table4", "roofline", "perf")
 
@@ -122,13 +121,6 @@ def _run_section(section: str, args, rows) -> None:
         print("\n# Trainer cycle time (build_trainer path; p4 = packed "
               "4-replica fleet)", flush=True)
         for r in cycle_time.run_benchmark(full=args.full):
-            rows.append((r["name"], r["us_per_call"], r["derived"]))
-
-    elif section == "trace_overhead":
-        from benchmarks import trace_overhead
-        print("\n# Tracing overhead (bare vs NullTracer vs enabled "
-              "tracer on the jitted cycle; target <2%)", flush=True)
-        for r in trace_overhead.run_benchmark(full=args.full):
             rows.append((r["name"], r["us_per_call"], r["derived"]))
 
     elif section == "per_ops":

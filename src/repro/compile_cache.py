@@ -12,6 +12,12 @@ again:
   derived from this file's location (the path is part of the cache key,
   so a directory that moves never hits). It is listed in ``.gitignore``.
 
+Either way the cache key includes the programs' metadata: op_name (where
+``jax.named_scope`` lands) and source locations. JAX leaves it out by
+default, so a program that differs from a cached one only in its named
+scopes would load an executable without them, and a profile of it could
+not attribute its ops (``core/concurrent.py`` ``CYCLE_SCOPES``).
+
 ``main()`` functions called in-process (the tests do) never enable it.
 """
 
@@ -28,6 +34,7 @@ DEFAULT_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 def enable() -> str:
     """Turn the persistent cache on; returns the directory it uses."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get(ENV_VAR)
     if env:
         return env

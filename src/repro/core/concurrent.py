@@ -52,7 +52,8 @@ from repro.core.dqn import make_update_fn
 from repro.core.replay import (ReplayState, per_flush_priorities, per_sample,
                                per_stage_priorities, per_tree,
                                replay_add_batch, replay_sample)
-from repro.core.synchronized import (Obs, SamplerState, nstep_aggregate,
+from repro.core.synchronized import (ENV_SCOPE, POLICY_SCOPE, RENDER_SCOPE,
+                                     Obs, SamplerState, nstep_aggregate,
                                      sync_round)
 from repro.envs.games import EnvSpec
 from repro.optim.schedule import linear_epsilon
@@ -85,6 +86,30 @@ def replica_key(tag: int, seed: jax.Array, step: jax.Array) -> jax.Array:
 # the same (seed, cycle index) draw identical keys (the concurrent ==
 # 1-seed-population bitwise guarantee depends on this single constant).
 EVAL_STREAM_TAG = 29
+
+# Named scopes of the C-cycle's phases. They change only the op_name
+# metadata of the compiled program, never its arithmetic; a profiler
+# trace of the cycle attributes each device op to the innermost one.
+ACT_SCOPE = "act"
+"""The sampler scan: all C/W synchronized rounds, acting from θ⁻."""
+PER_TREE_SCOPE = "per_tree"
+"""The sum-tree rebuild over the replay snapshot (PER variants only)."""
+LEARN_SCOPE = "learn"
+"""The trainer scan: all C/F minibatch updates."""
+SAMPLE_SCOPE = "sample"
+"""One update's replay draw; under PER the tree descent and weights too."""
+UPDATE_SCOPE = "update"
+"""One update's forward, backward and optimizer step, and its priority
+staging under PER."""
+FLUSH_SCOPE = "flush"
+"""The sync-point flush: staged priorities, n-step aggregation and the
+staged transitions into 𝒟."""
+CYCLE_SCOPES = (
+    ACT_SCOPE, f"{ACT_SCOPE}/{POLICY_SCOPE}", f"{ACT_SCOPE}/{ENV_SCOPE}",
+    f"{ACT_SCOPE}/{RENDER_SCOPE}", PER_TREE_SCOPE, LEARN_SCOPE,
+    f"{LEARN_SCOPE}/{SAMPLE_SCOPE}", f"{LEARN_SCOPE}/{UPDATE_SCOPE}",
+    FLUSH_SCOPE)
+"""Every scope path of the cycle, nested scopes joined by ``/``."""
 
 
 def make_concurrent_cycle(spec: EnvSpec, q_forward: Callable, opt,
@@ -132,8 +157,9 @@ def make_concurrent_cycle(spec: EnvSpec, q_forward: Callable, opt,
             s, tr = sync_round(spec, qf_act, target_params, s, eps, obs)
             return s, tr
 
-        sampler, staged = jax.lax.scan(
-            sample_body, carry.sampler, jnp.arange(rounds))
+        with jax.named_scope(ACT_SCOPE):
+            sampler, staged = jax.lax.scan(
+                sample_body, carry.sampler, jnp.arange(rounds))
         # staging buffer: (rounds, W, ...) stacked transitions
 
         # --- trainer: C/F updates on θ from the frozen snapshot --------
@@ -150,49 +176,60 @@ def make_concurrent_cycle(spec: EnvSpec, q_forward: Callable, opt,
         if variant.prioritized:
             # The snapshot's sampling distribution: one tree build at the
             # boundary, frozen for the whole training burst.
-            tree = per_tree(replay_snapshot)
-            beta = jnp.minimum(
-                1.0, variant.per_beta0 + (1.0 - variant.per_beta0)
-                * carry.step.astype(jnp.float32)
-                / variant.per_beta_anneal_steps)
+            with jax.named_scope(PER_TREE_SCOPE):
+                tree = per_tree(replay_snapshot)
+                beta = jnp.minimum(
+                    1.0, variant.per_beta0 + (1.0 - variant.per_beta0)
+                    * carry.step.astype(jnp.float32)
+                    / variant.per_beta_anneal_steps)
 
             def train_body(tc, k):
                 params, opt_state, pending = tc
                 ks, kn = split_update_key(k)
-                batch = per_sample(replay_snapshot, ks, cfg.minibatch_size,
-                                   beta, tree=tree, backend=kernel_backend)
-                params, opt_state, loss, td_abs = update_fn(
-                    params, target_params, opt_state, batch, kn)
-                pending = per_stage_priorities(pending, batch["index"],
-                                               td_abs, variant.per_alpha,
-                                               variant.per_eps)
+                with jax.named_scope(SAMPLE_SCOPE):
+                    batch = per_sample(replay_snapshot, ks,
+                                       cfg.minibatch_size, beta, tree=tree,
+                                       backend=kernel_backend)
+                with jax.named_scope(UPDATE_SCOPE):
+                    params, opt_state, loss, td_abs = update_fn(
+                        params, target_params, opt_state, batch, kn)
+                    pending = per_stage_priorities(
+                        pending, batch["index"], td_abs, variant.per_alpha,
+                        variant.per_eps)
                 return (params, opt_state, pending), loss
 
-            pending0 = jnp.zeros_like(replay_snapshot["priority"])
-            (params, opt_state, pending), losses = jax.lax.scan(
-                train_body, (carry.params, carry.opt_state, pending0),
-                jax.random.split(ktrain, updates))
+            with jax.named_scope(LEARN_SCOPE):
+                pending0 = jnp.zeros_like(replay_snapshot["priority"])
+                (params, opt_state, pending), losses = jax.lax.scan(
+                    train_body, (carry.params, carry.opt_state, pending0),
+                    jax.random.split(ktrain, updates))
         else:
             def train_body(tc, k):
                 params, opt_state = tc
                 ks, kn = split_update_key(k)
-                batch = replay_sample(replay_snapshot, ks, cfg.minibatch_size)
-                params, opt_state, loss, _ = update_fn(params, target_params,
-                                                       opt_state, batch, kn)
+                with jax.named_scope(SAMPLE_SCOPE):
+                    batch = replay_sample(replay_snapshot, ks,
+                                          cfg.minibatch_size)
+                with jax.named_scope(UPDATE_SCOPE):
+                    params, opt_state, loss, _ = update_fn(
+                        params, target_params, opt_state, batch, kn)
                 return (params, opt_state), loss
 
-            (params, opt_state), losses = jax.lax.scan(
-                train_body, (carry.params, carry.opt_state),
-                jax.random.split(ktrain, updates))
+            with jax.named_scope(LEARN_SCOPE):
+                (params, opt_state), losses = jax.lax.scan(
+                    train_body, (carry.params, carry.opt_state),
+                    jax.random.split(ktrain, updates))
 
         # --- flush at the sync point: staged priorities, then staged ---
         # experiences (new slots enter at the updated max priority) -----
-        replay = carry.replay
-        if variant.prioritized:
-            replay = per_flush_priorities(replay, pending)
-        agg = nstep_aggregate(staged, variant.n_step, cfg.discount)
-        flat = {k: v.reshape((-1,) + v.shape[2:]) for k, v in agg.items()}
-        replay = replay_add_batch(replay, flat)
+        with jax.named_scope(FLUSH_SCOPE):
+            replay = carry.replay
+            if variant.prioritized:
+                replay = per_flush_priorities(replay, pending)
+            agg = nstep_aggregate(staged, variant.n_step, cfg.discount)
+            flat = {k: v.reshape((-1,) + v.shape[2:])
+                    for k, v in agg.items()}
+            replay = replay_add_batch(replay, flat)
 
         metrics = {
             "loss": jnp.mean(losses),

@@ -29,6 +29,17 @@ from repro.core.policy import policy_step, stream_keys
 # or an ObsPipeline (pixels | vector) — see envs/preprocess.py.
 Obs = Union[int, ObsPipeline]
 
+# Named scopes of one synchronized round. They change only the op_name
+# metadata of the compiled program, never its arithmetic; inside the
+# C-cycle they nest under ``concurrent.ACT_SCOPE`` (whose module cannot
+# be imported from here), and a profiler trace reads them back.
+POLICY_SCOPE = "policy"
+"""The batched Q forward and the ε-greedy or noisy action draw."""
+ENV_SCOPE = "env"
+"""The vmapped env dynamics (``step_autoreset``)."""
+RENDER_SCOPE = "render"
+"""The observation render of the stepped envs (``obs_batch``)."""
+
 
 class SamplerState(NamedTuple):
     env_states: Dict[str, jax.Array]   # vmapped env states (leading W)
@@ -60,11 +71,15 @@ def sync_round(spec: EnvSpec, q_forward: Callable, params,
     # ONE batched Q call + per-stream ε draws — the same stateless
     # primitive the serving layer batches client streams through
     # (core/policy.py), so served actions match these bitwise.
-    actions = policy_step(q_forward, params, cur, eps, stream_keys(kact, W))
-    env_states, rewards, dones = jax.vmap(
-        lambda st, a, k: step_autoreset(spec, st, a, k)
-    )(s.env_states, actions, jax.random.split(kstep, W))
-    frame = obs_batch(pipe, spec, env_states)
+    with jax.named_scope(POLICY_SCOPE):
+        actions = policy_step(q_forward, params, cur, eps,
+                              stream_keys(kact, W))
+    with jax.named_scope(ENV_SCOPE):
+        env_states, rewards, dones = jax.vmap(
+            lambda st, a, k: step_autoreset(spec, st, a, k)
+        )(s.env_states, actions, jax.random.split(kstep, W))
+    with jax.named_scope(RENDER_SCOPE):
+        frame = obs_batch(pipe, spec, env_states)
     next_obs = push_frame(s.stack, frame)                   # pre-reset view
     new_stack = push_frame(reset_stack_where(s.stack, dones), frame)
     transitions = {"obs": cur, "action": actions, "reward": rewards,

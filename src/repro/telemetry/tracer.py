@@ -16,12 +16,15 @@ Design rules (docs/observability.md):
 
 * **Host-side only.** A span never enters a jitted program; tracing a
   run cannot change a single bit of its result (locked by
-  tests/test_telemetry.py). What a span around one jitted super-step
-  sees is the *fused* act+learn+sync program — the paper's whole point
-  is that those phases overlap inside the device program, so the
-  decomposable phases at the driver are cycle/eval/checkpoint/metrics,
-  and intra-cycle attribution comes from compile events + the roofline
-  tooling.
+  tests/test_telemetry.py). A span around one jitted super-step sees
+  the whole cycle; the phases inside it (act, learn, flush) are named
+  scopes of the device program (``core/concurrent.py``), read back from
+  a profiler trace.
+* **One clock with the profiler.** Each span is also a
+  ``jax.profiler.TraceAnnotation`` of the same name for its lifetime,
+  so a profiler session (``jax.profiler.trace``, or a capture from
+  TensorBoard's profile plugin) shows the spans on ``/host:CPU`` beside
+  the device ops.
 * **Explicit fencing.** JAX dispatch is async; a span that closes
   without :meth:`Tracer.fence` measures enqueue time, not compute.
   ``fence`` is ``jax.block_until_ready`` on the tracer (identity on
@@ -29,9 +32,7 @@ Design rules (docs/observability.md):
   bitwise-neutral.
 * **Zero cost when off.** :class:`NullTracer` has the identical public
   surface with every method a no-op returning the same types; hot
-  paths take a tracer unconditionally. Overhead target for an
-  *enabled* tracer on a jitted cycle: <2% (``benchmarks/run.py
-  --sections trace_overhead`` records it).
+  paths take a tracer unconditionally.
 * **Compile visibility.** ``jax.monitoring`` duration events (jaxpr
   trace, MLIR lowering, backend compile) are captured while a tracer
   is active, so a trace separates compile cost from steady-state —
@@ -83,9 +84,10 @@ def _install_listener() -> bool:
 
 
 class _Span:
-    """Reusable span context: records one ``span`` record on exit."""
+    """Reusable span context: records one ``span`` record on exit, and
+    holds a profiler ``TraceAnnotation`` of the same name while open."""
 
-    __slots__ = ("_tracer", "_name", "_attrs", "_start")
+    __slots__ = ("_tracer", "_name", "_attrs", "_start", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str,
                  attrs: Dict[str, Any]) -> None:
@@ -94,6 +96,9 @@ class _Span:
         self._attrs = attrs
 
     def __enter__(self) -> "_Span":
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation(self._name)
+        self._annotation.__enter__()
         self._tracer._stack.append(self._name)
         self._start = self._tracer._now_us()
         return self
@@ -102,6 +107,7 @@ class _Span:
         end = self._tracer._now_us()
         tr = self._tracer
         tr._stack.pop()
+        self._annotation.__exit__(exc_type, exc, tb)
         tr._emit_span(self._name, self._start, end - self._start,
                       depth=len(tr._stack) + 1,
                       parent=tr._stack[-1] if tr._stack else None,

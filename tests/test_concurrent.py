@@ -6,11 +6,17 @@
 2. decoupling — the actions taken during a cycle are identical whatever
    the trainer does (zero vs real learning rate), because the behaviour
    policy reads only θ⁻.
+
+The compiled cycle also carries the phases' named scopes in its op_name
+metadata, which a profiler trace reads back.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.config import DQNConfig
 from repro.configs.dqn_nature import NatureCNNConfig
@@ -20,7 +26,8 @@ from repro.optim import adamw
 from repro.core.dqn import make_update_fn
 from repro.core.replay import replay_init, replay_add_batch, replay_sample
 from repro.core.synchronized import sampler_init, sync_round
-from repro.core.concurrent import (TrainerCarry, make_concurrent_cycle,
+from repro.core.concurrent import (CYCLE_SCOPES, PER_TREE_SCOPE,
+                                   TrainerCarry, make_concurrent_cycle,
                                    prepopulate, replica_key)
 from repro.optim.schedule import linear_epsilon
 
@@ -126,3 +133,49 @@ def test_target_refresh_at_boundary():
              for a, b in zip(jax.tree_util.tree_leaves(c1.params),
                              jax.tree_util.tree_leaves(carry.params))]
     assert max(diffs) > 0
+
+
+def scopes_found(hlo_text):
+    """The scopes of ``CYCLE_SCOPES`` present in compiled HLO's op_name
+    metadata. Each op_name component loses its transform wrappers
+    (``vmap(act)`` -> ``act``), and a nested scope may have other
+    components between it and its parent (``act/while/body/render``)."""
+    paths = set()
+    for name in set(re.findall(r'op_name="([^"]*)"', hlo_text)):
+        parts = []
+        for part in name.split("/"):
+            while (m := re.fullmatch(r"[\w.]+\((.+)\)", part)):
+                part = m.group(1)
+            parts.append(part)
+        paths.add("/" + "/".join(parts) + "/")
+    found = set()
+    for scope in CYCLE_SCOPES:
+        pattern = re.compile(
+            "/" + "/(?:.*/)?".join(map(re.escape, scope.split("/"))) + "/")
+        if any(pattern.search(p) for p in paths):
+            found.add(scope)
+    return found
+
+
+@pytest.mark.parametrize("mode", ["concurrent", "population"])
+@pytest.mark.parametrize("variant", ["dqn", "rainbow"])
+def test_compiled_cycle_carries_the_phase_scopes(variant, mode):
+    """Every scope of the cycle lands in the compiled program's op_name
+    metadata, nested as named, in the single-replica cycle and under
+    the population's vmap; per_tree only where replay is prioritized."""
+    from repro.api import AlgoSpec, ExperimentSpec, ScheduleSpec, build_trainer
+    from repro.configs.dqn_nature import get_variant
+
+    spec = ExperimentSpec(
+        mode=mode, variant=get_variant(variant), envs=4, frame_size=10,
+        net="tiny",
+        schedule=ScheduleSpec(cycles=1, cycle_steps=16, prepopulate=32,
+                              eval_every=1, eval_episodes=4),
+        algo=AlgoSpec(minibatch_size=8, replay_capacity=128,
+                      train_period=4, eps_anneal_steps=1000))
+    trainer = build_trainer(spec)
+    text = trainer.cycle.lower(trainer.init_template()).compile().as_text()
+    want = set(CYCLE_SCOPES)
+    if variant == "dqn":
+        want.discard(PER_TREE_SCOPE)
+    assert scopes_found(text) == want

@@ -259,6 +259,31 @@ def test_tracer_fence_returns_value():
     tr.close()
 
 
+def test_tracer_spans_land_on_the_profilers_host_plane(tmp_path):
+    """A span is a profiler annotation too: opened under a profiler
+    session, it shows by name on the trace's ``/host:CPU`` plane, on
+    the clock the device ops use."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    tr = Tracer((), capture_compiles=False)
+    with jax.profiler.trace(str(tmp_path)):
+        with tr.span("cycle"):
+            with tr.span("checkpoint"):
+                jnp.arange(5).block_until_ready()
+    tr.close()
+    files = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    assert len(files) == 1
+    plane = ProfileData.from_file(files[0]).find_plane_with_name("/host:CPU")
+    events = {e.name: (e.start_ns, e.start_ns + e.duration_ns)
+              for line in plane.lines for e in line.events}
+    assert {"cycle", "checkpoint"} <= set(events)
+    outer, inner = events["cycle"], events["checkpoint"]
+    assert outer[0] <= inner[0] and inner[1] <= outer[1]
+
+
 # ---------------------------------------------------------------------------
 # 3. compile-event capture (jax.monitoring)
 # ---------------------------------------------------------------------------

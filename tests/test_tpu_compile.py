@@ -87,11 +87,15 @@ def test_categorical_projection_compiles_for_v5e(one_chip, vmapped):
 
 def test_rainbow_population_cycle_compiles_for_v5e(one_chip, monkeypatch):
     """The rainbow cycle at 84x84x4 with both Mosaic kernels inside it
-    compiles for one v5e and fits its HBM."""
+    compiles for one v5e and fits its HBM, and every phase's named scope
+    survives the TPU compiler's fusion into its op_name metadata."""
     import dataclasses
+
+    from test_concurrent import scopes_found
 
     from repro.api import ExperimentSpec, build_trainer
     from repro.configs.dqn_nature import get_variant
+    from repro.core.concurrent import CYCLE_SCOPES
 
     monkeypatch.setenv("REPRO_KERNEL_BACKEND", "mosaic")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -104,7 +108,9 @@ def test_rainbow_population_cycle_compiles_for_v5e(one_chip, monkeypatch):
     carry = jax.tree.map(lambda s: _shape(one_chip, s.shape, s.dtype),
                          trainer.init_template())
     compiled = trainer.cycle.lower(carry).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert scopes_found(text) == set(CYCLE_SCOPES)
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
