@@ -23,6 +23,22 @@ Transitions are stored as full (obs, action, reward, next_obs, done)
 records. Storage dtype for observations is uint8 (the paper's 1-byte
 pixel economy).
 
+How a frame stack is stored follows its shape and dtype alone. A uint8
+stack of at least one lane row (128 32-bit words, 512 bytes) is packed
+four bytes to a ``uint32`` word and zero-padded to a whole number of
+lane rows: ``u32[capacity, words]`` (84x84x4: 7,056 words padded to
+7,168). A TPU's default layout puts the dimension that needs the least
+padding in the lanes; a raw stack's row (84*84*4 = 28,224 bytes) fits
+no lane multiple, so the capacity axis took the lanes and XLA
+relayouted the whole store around every draw and every flush. The
+aligned row keeps the row-major layout that the gather and the scatter
+use, so the store is never relayouted. Smaller or non-uint8 stacks
+(vector observations) are stored as they are. ``replay_add_batch``
+packs, and ``replay_sample`` / ``per_sample`` unpack the minibatch, so
+the bytes that leave the API are the bytes that came in. ``obs_like``,
+a zero-size ``[0, *obs_shape]`` array of the stack's dtype, carries the
+stack's shape for the unpacking.
+
 This module is the public replay API (the concurrent cycle, the
 baselines and the disaggregated learner all import from here); the
 staging/flush timeline is diagrammed in docs/architecture.md.
@@ -30,6 +46,7 @@ staging/flush timeline is diagrammed in docs/architecture.md.
 
 from __future__ import annotations
 
+import math
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -39,25 +56,95 @@ from repro.kernels import ops as kops
 from repro.kernels.segment_tree import next_pow2, tree_build
 
 __all__ = [
-    "ReplayState", "FIELDS", "replay_init", "replay_capacity",
-    "replay_size", "replay_is_prioritized", "replay_add_batch",
-    "replay_sample", "per_tree", "stratified_indices", "per_sample",
-    "per_stage_priorities", "per_flush_priorities",
+    "ReplayState", "FIELDS", "frame_words", "replay_init",
+    "replay_capacity", "replay_size", "replay_is_prioritized",
+    "replay_add_batch", "replay_sample", "per_tree", "stratified_indices",
+    "per_sample", "per_stage_priorities", "per_flush_priorities",
 ]
 
 ReplayState = Dict[str, jax.Array]
 
 FIELDS = ("obs", "action", "reward", "next_obs", "done")
+FRAMES = ("obs", "next_obs")
+
+LANES = 128      # 32-bit words in one lane row of a TPU tile
+BYTE_SHIFTS = (0, 8, 16, 24)   # byte k of a packed word: bits 8k..8k+7
+PACK_ROWS = 4096   # stacks packed at a time on the way in
+
+
+def frame_words(obs_shape: Tuple[int, ...], obs_dtype) -> int:
+    """32-bit words that one stored frame stack takes, a whole number of
+    128-word lane rows, or 0 where the stack is stored as it is (not
+    uint8, or under one lane row)."""
+    words = -(-math.prod(obs_shape) // 4)
+    if jnp.dtype(obs_dtype) != jnp.uint8 or words < LANES:
+        return 0
+    return -(-words // LANES) * LANES
+
+
+def _pack(frames: jax.Array, width: int) -> jax.Array:
+    """u8[n, *obs_shape] -> u32[n, width]: four bytes a word, in order,
+    zero-padded to ``width`` words."""
+    n = frames.shape[0]
+    flat = frames.reshape(n, -1)
+    flat = jnp.pad(flat, ((0, 0), (0, -flat.shape[1] % 4)))
+    quads = flat.reshape(n, -1, 4).astype(jnp.uint32)
+    words = jnp.sum(quads << jnp.array(BYTE_SHIFTS, jnp.uint32), axis=-1,
+                    dtype=jnp.uint32)
+    return jnp.pad(words, ((0, 0), (0, width - words.shape[1])))
+
+
+def _unpack(words: jax.Array, obs_shape: Tuple[int, ...]) -> jax.Array:
+    """u32[n, width] -> u8[n, *obs_shape]: drops the padding. The barrier
+    keeps the layout the consumer wants (the first conv takes the batch
+    in the lanes) from reaching back into the unpacking, so the words
+    are split with a frame row in the lanes (84 of 128 used at 84x84)
+    and not the batch (32 of 128)."""
+    n, size = words.shape[0], math.prod(obs_shape)
+    quads = (words[:, :-(-size // 4), None]
+             >> jnp.array(BYTE_SHIFTS, jnp.uint32)) & 0xFF
+    flat = quads.astype(jnp.uint8).reshape(n, -1)[:, :size]
+    return jax.lax.optimization_barrier(flat.reshape((n,) + obs_shape))
+
+
+def _set_frames(state: ReplayState, store: jax.Array, idx: jax.Array,
+                frames: jax.Array) -> jax.Array:
+    """``store.at[idx].set(frames)`` with the stacks as the state stores
+    them, packed ``PACK_ROWS`` at a time: packing a whole prepopulate in
+    one go (50,000 stacks at 84x84x4) holds ~3 GB of temporaries."""
+    like = state["obs_like"]
+    width = frame_words(like.shape[1:], like.dtype)
+    frames = frames.astype(like.dtype)
+    if not width:
+        return store.at[idx].set(frames)
+    for s in range(0, idx.shape[0], PACK_ROWS):
+        rows = _pack(frames[s:s + PACK_ROWS], width)
+        store = store.at[idx[s:s + PACK_ROWS]].set(rows)
+    return store
+
+
+def _gather(state: ReplayState, idx: jax.Array) -> Dict[str, jax.Array]:
+    out = {k: state[k][idx] for k in FIELDS}
+    like = state["obs_like"]
+    if frame_words(like.shape[1:], like.dtype):
+        for k in FRAMES:
+            out[k] = _unpack(out[k], like.shape[1:])
+    return out
 
 
 def replay_init(capacity: int, obs_shape: Tuple[int, ...],
                 obs_dtype=jnp.uint8, prioritized: bool = False) -> ReplayState:
+    obs_shape = tuple(obs_shape)
+    width = frame_words(obs_shape, obs_dtype)
+    frames = (((capacity, width), jnp.uint32) if width
+              else ((capacity,) + obs_shape, obs_dtype))
     state = {
-        "obs": jnp.zeros((capacity,) + obs_shape, obs_dtype),
+        "obs": jnp.zeros(*frames),
         "action": jnp.zeros((capacity,), jnp.int32),
         "reward": jnp.zeros((capacity,), jnp.float32),
-        "next_obs": jnp.zeros((capacity,) + obs_shape, obs_dtype),
+        "next_obs": jnp.zeros(*frames),
         "done": jnp.zeros((capacity,), jnp.bool_),
+        "obs_like": jnp.zeros((0,) + obs_shape, obs_dtype),
         "cursor": jnp.zeros((), jnp.int32),
         "size": jnp.zeros((), jnp.int32),
     }
@@ -109,7 +196,10 @@ def replay_add_batch(state: ReplayState, batch: Dict[str, jax.Array]) -> ReplayS
     idx = (state["cursor"] + offset) % cap
     new = dict(state)
     for k in FIELDS:
-        new[k] = state[k].at[idx].set(batch[k].astype(state[k].dtype))
+        if k in FRAMES:
+            new[k] = _set_frames(state, state[k], idx, batch[k])
+        else:
+            new[k] = state[k].at[idx].set(batch[k].astype(state[k].dtype))
     if replay_is_prioritized(state):
         new["priority"] = state["priority"].at[idx].set(state["max_priority"])
     new["cursor"] = (state["cursor"] + n) % cap
@@ -126,7 +216,7 @@ def replay_sample(state: ReplayState, key: jax.Array, n: int) -> Dict[str, jax.A
     out-of-range read — locked in by
     test_replay_wraparound.test_uniform_sample_masks_unfilled_slots."""
     idx = jax.random.randint(key, (n,), 0, jnp.maximum(state["size"], 1))
-    return {k: state[k][idx] for k in FIELDS}
+    return _gather(state, idx)
 
 
 # ---------------------------------------------------------------------------
@@ -179,7 +269,7 @@ def per_sample(state: ReplayState, key: jax.Array, n: int, beta: jax.Array,
                         1e-30)
     w = (size.astype(jnp.float32) * probs) ** (-beta)
     w = w / jnp.maximum(jnp.max(w), 1e-30)
-    out = {k: state[k][idx] for k in FIELDS}
+    out = _gather(state, idx)
     out["index"] = idx
     out["weight"] = w
     return out
