@@ -1,5 +1,8 @@
 """FLOPs of the Nature-CNN Q-network and of the learner per env step.
 
+The shared count: a configuration without a ``bench/counts/<config>.py``
+of its own is counted here (``bench.cells.load_count``).
+
 A FLOP here is one multiply or one add of a convolution or a dense
 layer (2 per multiply-accumulate). Activations, softmaxes, the noise of
 noisy layers and the optimizer are not counted: against the matmul and

@@ -161,14 +161,14 @@ def reference_runs(cell: cells.Cell, seeds: List[int], devices,
                    dtype=None) -> List[Dict[str, Any]]:
     """The reference's first ``CHECK_CYCLES`` cycles for each seed, one
     replica per seed, vmapped and split over ``devices`` where there
-    are several."""
+    are several. The reference is the configuration's own, from the
+    cell's checkout (``cells.load_reference``)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec
 
-    from bench.reference.cycle import Reference
-
+    Reference = cells.load_reference(cell.config_name, cell.root)
     ref = Reference(cell.config, cell.envs,
                     dtype=jnp.float32 if dtype is None else dtype)
     init = jax.vmap(ref.init)

@@ -4,9 +4,10 @@ A run drives the program's compiled cycle from the seed through its
 first three cycles and keeps, per replica: each cycle's mean loss, the
 optimizer's first-moment state after the first cycle (the gradients as
 the optimizer got them: RMSProp's ``g``, Adam's ``m``), and the
-parameters before the first cycle and after the third. The reference
-(``bench/reference/cycle.py``) does the same from the same seed. Three
-numbers compare them:
+parameters before the first cycle and after the third. The
+configuration's reference (``bench/reference/<config>.py``, else the
+shared ``cycle.py``) does the same from the same seed. Four numbers
+compare them:
 
 * ``first_loss_gap``: |program - reference| / |reference| of the first
   cycle's loss. Its learner samples only the prepopulated replay with

@@ -1,5 +1,9 @@
 """Plain float32 reference of one replica's training cycle.
 
+The shared reference: every configuration without a
+``bench/reference/<config>.py`` of its own runs this one, and such a
+module may subclass it (``bench/reference/__init__.py``).
+
 This module imports nothing from the program under test. It rebuilds,
 from the configuration file's numbers and the seed alone, what the
 program's population trainer computes for one replica:

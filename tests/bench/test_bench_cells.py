@@ -7,7 +7,7 @@ import shutil
 
 import pytest
 
-from tiny_cell import ROOT
+from tiny_cell import ROOT, add_config, make
 
 from bench import cells
 
@@ -71,11 +71,28 @@ def test_spec_round_trips_through_experiment_spec(workload):
         cell.config["spec"]["schedule"]["cycle_steps"]
 
 
-@pytest.mark.parametrize("config", cells.names("configs"))
+def _on_shared_reference(config, bench=cells.BENCH_DIR):
+    return cells.config_file("reference", config, bench) == (
+        bench / "reference" / "cycle.py")
+
+
+def configs_without_their_test(bench, tests):
+    """The configurations in ``bench`` that bring their own reference
+    but no ``test_config_<config>.py`` with a test in ``tests``."""
+    def has_test(config):
+        path = tests / f"test_config_{config}.py"
+        return path.is_file() and "def test_" in path.read_text()
+    return [c for c in cells.names("configs", bench)
+            if not _on_shared_reference(c, bench) and not has_test(c)]
+
+
+@pytest.mark.parametrize("config", [c for c in cells.names("configs")
+                                    if _on_shared_reference(c)])
 def test_config_network_and_constants_are_the_programs(config):
-    """The reference and the counts read the network and the optimizer
+    """The shared reference and count read the network and the optimizer
     constants from the configuration file; they must be what the
-    program builds."""
+    program builds. A configuration with a reference of its own is
+    checked by its own ``test_config_<config>.py``."""
     from repro.config import DQNConfig
     from repro.configs.dqn_nature import cnn_geometry
     from repro.optim import adamw, centered_rmsprop
@@ -97,6 +114,26 @@ def test_config_network_and_constants_are_the_programs(config):
                                      adam["grad_clip"].default)
     assert (k["eps_start"], k["eps_end"]) == (DQNConfig.eps_start,
                                               DQNConfig.eps_end)
+
+
+def test_every_configuration_with_its_own_reference_has_its_own_test():
+    assert configs_without_their_test(cells.BENCH_DIR, ROOT / "tests"
+                                      / "bench") == []
+    for config in cells.names("configs"):
+        assert isinstance(cells.load_reference(config), type)
+        assert callable(cells.load_count(config))
+
+
+def test_own_reference_without_its_test_is_found(tmp_path):
+    bench = make(tmp_path, "dqn-nature")
+    tests = tmp_path / "tests"
+    tests.mkdir()
+    add_config(bench, "tiny-own", reference="Reference = object\n")
+    add_config(bench, "tiny-shared", count="flops_per_env_step = abs\n")
+    assert configs_without_their_test(bench, tests) == ["tiny-own"]
+    (tests / "test_config_tiny-own.py").write_text(
+        "def test_program_check():\n    pass\n")
+    assert configs_without_their_test(bench, tests) == []
 
 
 @pytest.mark.parametrize("seed,replicas", [(0, 1), (2 ** 31 + 5, 1),

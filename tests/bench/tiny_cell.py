@@ -4,7 +4,8 @@
 into ``tmp_path`` and adds one configuration shrunk from ``config``
 (10x10 frames, a one-conv net, C=32, replay 256) with its traffic and
 its cell, ``tiny.p1``. The output check's limits are the real
-configuration's, copied.
+configuration's, copied. ``add_config`` adds the same configuration
+under another name, with a reference or FLOP count of its own.
 """
 
 import copy
@@ -45,3 +46,26 @@ def make(tmp_path: Path, config: str) -> Path:
     if limits.is_file():
         shutil.copy(limits, bench / "limits" / "tiny.json")
     return bench
+
+
+def add_config(bench: Path, name: str, reference: str = "",
+               count: str = "") -> str:
+    """Adds configuration ``name``, the tiny one under another name,
+    with its limits and its cell ``<name>.p1``, and, where given, the
+    source of its own ``reference/<name>.py`` and ``counts/<name>.py``.
+    Returns the cell's name."""
+    cfg = json.loads((bench / "configs" / "tiny.json").read_text())
+    cfg["name"] = name
+    (bench / "configs" / f"{name}.json").write_text(json.dumps(cfg))
+    limits = bench / "limits" / "tiny.json"
+    if limits.is_file():
+        shutil.copy(limits, bench / "limits" / f"{name}.json")
+    cell = f"{name}.p1"
+    (bench / "workloads" / f"{cell}.json").write_text(json.dumps(
+        {"name": cell, "config": name, "traffic": "tiny4", "chips": 1,
+         "why": "harness tests on the CPU"}))
+    if reference:
+        (bench / "reference" / f"{name}.py").write_text(reference)
+    if count:
+        (bench / "counts" / f"{name}.py").write_text(count)
+    return cell
