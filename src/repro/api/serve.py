@@ -362,6 +362,11 @@ def load_policy(ckpt_dir: str, spec: Optional[ExperimentSpec] = None,
         raise ValueError(
             f"{ckpt_dir} holds no spec.json — pass the run's "
             "ExperimentSpec explicitly (rl_train --print-spec emits it)")
+    if spec.variant.recurrent:
+        raise ValueError(
+            f"net {spec.net!r} is recurrent: a served action depends on "
+            "the stream's LSTM state, and the policy server is stateless "
+            "per request; serving it is not supported")
     trainer = build_trainer(spec)
     template = trainer.init_template()
     skipped: List[str] = []

@@ -62,6 +62,12 @@ class ScheduleSpec:
     prepopulate: int = 2048   # N: uniform-random transitions seeding 𝒟
     eval_every: int = 20      # cycles between ε=0.05 evaluations
     eval_episodes: int = 64   # parallel evaluation streams per eval
+    # cycles between θ⁻ ← θ syncs. 1: θ⁻ is synced at every cycle
+    # boundary (the paper's C-cycle). A recurrent variant's boundary
+    # refreshes the acting parameters (R2D2's actor refresh) and θ⁻
+    # follows every K cycles (R2D2's target period, in updates, is
+    # K × cycle_steps / train_period); only it may set K > 1.
+    target_cycles: int = 1
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,6 +85,11 @@ class AlgoSpec:
     learning_rate: float = 0.0   # 0.0 = the optimizer's default
                                  # (adamw 1e-3, rmsprop 2.5e-4)
     eps_anneal_steps: int = 0    # 0 = derive cycles * cycle_steps // 2
+    # Adam ("adamw") only: second-moment decay, ε in the denominator and
+    # the global-norm gradient clip (0.0 = no clip)
+    adam_b2: float = 0.95
+    adam_eps: float = 1e-8
+    adam_grad_clip: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -121,10 +132,11 @@ class ExperimentSpec:
     # deep_q_rl machine-state lineage).
     obs_mode: str = "pixels"
     frame_size: int = 10          # 10 (MinAtar grids) or 84 (Nature geometry)
-    # Q-network geometry preset (configs/dqn_nature.cnn_geometry):
-    # "auto" = frame_size pick (10 -> "small", 84 -> "nature") or, under
-    # obs_mode="vector", the fc-only "mlp"; "tiny"/"mlp_tiny" are the
-    # dryrun/tests networks.
+    # Q-network (api/nets.NETS): a Nature-CNN geometry preset
+    # (configs/dqn_nature.cnn_geometry): "auto" = frame_size pick
+    # (10 -> "small", 84 -> "nature") or, under obs_mode="vector", the
+    # fc-only "mlp"; "tiny"/"mlp_tiny" are the dryrun/tests networks;
+    # or "r2d2", the recurrent network of a sequence-replay variant.
     net: str = "auto"
     seed: int = 0                 # base replica seed (replica r: seed + r)
     seeds: int = 1                # population size P (population mode)
@@ -137,7 +149,7 @@ class ExperimentSpec:
     # -- validation --------------------------------------------------------
 
     def validate(self) -> None:
-        from repro.configs.dqn_nature import NET_PRESETS
+        from repro.api.nets import check_net
         from repro.envs import make_env
         if self.mode not in MODES:
             raise ValueError(f"unknown mode {self.mode!r}; one of {MODES}")
@@ -148,9 +160,7 @@ class ExperimentSpec:
             raise ValueError(
                 f"unknown obs_mode {self.obs_mode!r}; one of "
                 "('pixels', 'vector')")
-        if self.net not in NET_PRESETS:
-            raise ValueError(
-                f"unknown net {self.net!r}; one of {NET_PRESETS}")
+        check_net(self)
         mlp_net = self.net in ("mlp", "mlp_tiny")
         if self.obs_mode == "vector" and not (mlp_net or self.net == "auto"):
             raise ValueError(
@@ -185,7 +195,8 @@ class ExperimentSpec:
                         ("cycle_steps", self.schedule.cycle_steps),
                         ("minibatch_size", self.algo.minibatch_size),
                         ("replay_capacity", self.algo.replay_capacity),
-                        ("train_period", self.algo.train_period)):
+                        ("train_period", self.algo.train_period),
+                        ("target_cycles", self.schedule.target_cycles)):
             if v < 1:
                 raise ValueError(f"{name} must be >= 1, got {v}")
         # the driver loop computes `(cycle + 1) % cadence` — a 0 cadence
@@ -202,6 +213,48 @@ class ExperimentSpec:
                     "runs the final cycle; for final-cycle-only "
                     f"behaviour set {name} = schedule.cycles)")
         self.variant.validate()
+        if self.variant.recurrent:
+            self._validate_sequences()
+        elif self.schedule.target_cycles != 1:
+            raise ValueError(
+                f"schedule.target_cycles={self.schedule.target_cycles} "
+                "needs a recurrent variant: the feed-forward cycle syncs "
+                "θ⁻ at every boundary")
+
+    def _validate_sequences(self) -> None:
+        """The sequence replay's geometry: each stream's ring holds a
+        whole number of sequence periods, and cycles and prepopulation
+        end on a period boundary."""
+        v, W = self.variant, self.envs
+        period = v.sequence_period
+        if self.algo.replay_capacity % W:
+            raise ValueError(
+                f"replay_capacity counts sequences, a whole number per "
+                f"stream: {self.algo.replay_capacity} % envs {W} != 0")
+        for name, steps in (("schedule.cycle_steps",
+                             self.schedule.cycle_steps),
+                            ("schedule.prepopulate",
+                             self.schedule.prepopulate)):
+            if steps % (W * period):
+                raise ValueError(
+                    f"{name}={steps} must be a multiple of envs x "
+                    f"sequence_period = {W * period}")
+        span = v.burn_in + v.sequence_length + 1
+        if self.schedule.prepopulate // W < span:
+            raise ValueError(
+                f"prepopulate gives each stream "
+                f"{self.schedule.prepopulate // W} steps; one sequence "
+                f"spans {span}")
+        # a sequence also reads the frame_stack - 1 frames before it,
+        # from the period before its start
+        from repro.api.nets import frame_stack
+        ring = self.algo.replay_capacity // W * period
+        if (period < frame_stack(self) - 1
+                or ring < period * (-(-span // period) + 1)):
+            raise ValueError(
+                f"a stream's ring of {ring} steps ({period}-step periods) "
+                f"holds no whole sequence of {span} steps with the "
+                "period before it")
 
     # -- derived runtime configs ------------------------------------------
 
@@ -228,9 +281,7 @@ class ExperimentSpec:
         sched, algo = self.schedule, self.algo
         eps_anneal = algo.eps_anneal_steps or max(
             sched.cycles * sched.cycle_steps // 2, 1)
-        from repro.configs.dqn_nature import cnn_geometry
-        frame_stack = cnn_geometry(self.net, self.frame_size, 1,
-                                   obs_dim=self.obs_dim()).frame_stack
+        from repro.api.nets import frame_stack
         return DQNConfig(
             minibatch_size=algo.minibatch_size,
             replay_capacity=algo.replay_capacity,
@@ -238,12 +289,13 @@ class ExperimentSpec:
             train_period=algo.train_period,
             prepopulate=sched.prepopulate,
             n_envs=self.envs,
-            frame_stack=frame_stack,
+            frame_stack=frame_stack(self),
             eps_anneal_steps=eps_anneal,
             discount=algo.discount,
             concurrent=self.mode in ("concurrent", "population"),
             synchronized=self.mode != "baseline",
-            variant=self.variant)
+            variant=self.variant,
+            target_cycles=sched.target_cycles)
 
     # -- construction helpers ---------------------------------------------
 

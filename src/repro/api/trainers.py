@@ -63,6 +63,7 @@ from typing import Any, Callable, Dict, Optional, Protocol, Tuple, \
 import jax
 import jax.numpy as jnp
 
+from repro.api.nets import build_net
 from repro.api.spec import ExperimentSpec, MODES
 from repro.core.baseline import BaselineCarry, make_baseline_chunk
 from repro.core.concurrent import (EVAL_STREAM_TAG, TrainerCarry,
@@ -76,7 +77,6 @@ from repro.core.replay import replay_init
 from repro.core.synchronized import evaluate, sampler_init
 from repro.envs import make_env
 from repro.envs.preprocess import pixel_obs, vector_obs
-from repro.models.nature_cnn import q_forward, q_init, q_logits
 from repro.optim import adamw, centered_rmsprop
 
 __all__ = ["Trainer", "TRAINERS", "register_trainer", "build_trainer",
@@ -161,29 +161,27 @@ def build_packed_fleet(spec: ExperimentSpec, seeds) -> Trainer:
 # ---------------------------------------------------------------------------
 
 class _Components:
-    """env spec + obs pipeline + network/DQN configs + forward fns +
-    optimizer."""
+    """env spec + obs pipeline + DQN config + the network's parts
+    (``api/nets.py``) + optimizer."""
 
     def __init__(self, spec: ExperimentSpec):
         self.env = make_env(spec.env, **spec.env_params)
         # the observation pipeline every sampler/eval path consumes
         self.obs = (vector_obs(self.env) if spec.obs_mode == "vector"
                     else pixel_obs(spec.frame_size))
-        self.ncfg = spec.cnn_config(self.env.n_actions)
         self.dcfg = spec.dqn_config()
-        ec = spec.exec
-        ncfg = self.ncfg
-        # trailing noise key (NoisyNet; None = μ-only, e.g. greedy eval)
-        self.qf = lambda p, o, k=None: q_forward(p, o, ncfg, ec, noise_key=k)
-        self.qlog = ((lambda p, o, k=None: q_logits(p, o, ncfg, ec,
-                                                    noise_key=k))
-                     if spec.variant.distributional else None)
-        lr = spec.algo.learning_rate
-        if spec.algo.optimizer == "rmsprop":
+        # the network's parts (api/nets.py): ``qf`` is the feed-forward
+        # Q function, or the recurrent net's step functions
+        net = build_net(spec, self.env)
+        self.qf, self.qlog, self.q_init = net.qf, net.qlog, net.init
+        algo = spec.algo
+        lr = algo.learning_rate
+        if algo.optimizer == "rmsprop":
             self.opt = centered_rmsprop(lr or 2.5e-4)
         else:
-            self.opt = adamw(lr or 1e-3, weight_decay=0.0)
-        self.q_init = lambda key: q_init(ncfg, self.env.n_actions, key)
+            self.opt = adamw(lr or 1e-3, b2=algo.adam_b2, eps=algo.adam_eps,
+                             weight_decay=0.0,
+                             grad_clip=algo.adam_grad_clip or None)
 
 
 def _expand_replica_axis(metrics: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
@@ -349,6 +347,8 @@ class _SequentialTrainer(_SingleReplicaTrainer):
         bad = [t for t in _STAGING_TOGGLES if getattr(spec.variant, t)]
         if spec.variant.n_step > 1:
             bad.append(f"n_step={spec.variant.n_step}")
+        if spec.variant.recurrent:
+            bad.append("sequence replay")
         if bad:
             raise ValueError(
                 f"mode {spec.mode!r} runs standard sequential DQN and "

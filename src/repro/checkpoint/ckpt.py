@@ -44,6 +44,8 @@ RESTORE_ERRORS = (OSError, ValueError, EOFError, KeyError,
 
 
 def _flatten(tree: Any, prefix: str = ""):
+    if tree is None:            # an absent part (a pytree with no leaves)
+        return
     if isinstance(tree, dict):
         for k in sorted(tree):
             yield from _flatten(tree[k], f"{prefix}{k}{SEP}")
@@ -97,6 +99,8 @@ def _fsync_dir(path: str) -> None:
 
 
 def _unflatten_into(template: Any, arrays, prefix: str = ""):
+    if template is None:
+        return None
     if isinstance(template, dict):
         return {k: _unflatten_into(template[k], arrays, f"{prefix}{k}{SEP}")
                 for k in template}

@@ -300,6 +300,7 @@ class VariantConfig:
     per_alpha: float = 0.6        # priority exponent (Schaul et al. Table 3)
     per_beta0: float = 0.4        # initial IS-correction exponent
     per_beta_anneal_steps: int = 1_000_000   # beta -> 1 over this horizon
+                                             # (0: beta stays per_beta0)
     per_eps: float = 1e-3         # additive mass so td=0 stays sampleable
     # distributional: C51 categorical value head (num_atoms × actions
     # logits), cross-entropy loss against the categorical_projection of
@@ -315,6 +316,26 @@ class VariantConfig:
     # the bitwise-determinism guarantee
     noisy: bool = False
     noisy_sigma0: float = 0.5     # σ-parameter init scale σ0/√fan_in
+    # recurrent (R2D2, Kapturowski et al. 2019): a sequence_length > 0
+    # replays sequences of burn_in + sequence_length steps (+1 to
+    # bootstrap the last) that start every sequence_period steps of a
+    # stream, with the recurrent state stored at each start; the first
+    # burn_in steps are unrolled without gradient. 0 = transitions.
+    sequence_length: int = 0
+    burn_in: int = 0
+    sequence_period: int = 0
+    # sequence priority η·max|δ| + (1 - η)·mean|δ| over its trained steps
+    priority_eta: float = 0.9
+    # value rescaling h(x) = sign(x)(√(|x|+1) - 1) + εx of the targets
+    # (Pohlen et al. 2018); > 0 when recurrent
+    value_rescale_eps: float = 0.0
+    # Ape-X per-stream exploration ε_i = actor_eps^(1 + 7·i/(W-1)) (Horgan
+    # et al. 2018); > 0 when recurrent
+    actor_eps: float = 0.0
+
+    @property
+    def recurrent(self) -> bool:
+        return self.sequence_length > 0
 
     def validate(self) -> None:
         assert self.n_step >= 1, self.n_step
@@ -325,6 +346,16 @@ class VariantConfig:
         if self.distributional:
             assert self.num_atoms >= 2, "C51 needs a non-degenerate support"
         assert self.noisy_sigma0 >= 0.0, self.noisy_sigma0
+        if self.recurrent:
+            assert self.prioritized and self.double and not (
+                self.distributional or self.noisy), \
+                "sequence replay is prioritized double Q, with a scalar, " \
+                "noise-free head"
+            assert self.sequence_period >= 1 and self.burn_in >= 1, (
+                self.sequence_period, self.burn_in)
+            assert 0.0 <= self.priority_eta <= 1.0, self.priority_eta
+            assert self.value_rescale_eps > 0.0, self.value_rescale_eps
+            assert 0.0 < self.actor_eps <= 1.0, self.actor_eps
 
 
 @dataclasses.dataclass(frozen=True)
@@ -350,6 +381,10 @@ class DQNConfig:
     concurrent: bool = True              # Concurrent Training enabled
     synchronized: bool = True            # Synchronized Execution enabled
     variant: VariantConfig = VariantConfig()   # off-policy variant family
+    # cycles between θ⁻ ← θ syncs, in a recurrent variant's cycle, which
+    # keeps θ⁻ in the carry and acts from θ at the boundary; the
+    # feed-forward cycle syncs θ⁻ at every boundary and acts from it
+    target_cycles: int = 1
 
     @property
     def updates_per_cycle(self) -> int:

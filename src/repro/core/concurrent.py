@@ -33,11 +33,13 @@ pure function of its carry and a vmapped population of carries with
 distinct seeds (core/population.py) runs decorrelated replicas. C51
 losses ride the same PER staging with cross-entropy in place of |td|.
 Every variant therefore keeps the paper's snapshot-𝒟 determinism
-guarantee — locked in by tests/test_variants.py. docs/architecture.md
-has the cycle timeline. Launchers construct this cycle through the
-``concurrent`` / ``population`` entries of the ``repro.api`` trainer
-registry (docs/experiment_api.md) rather than calling
-``make_concurrent_cycle`` directly.
+guarantee — locked in by tests/test_variants.py. A recurrent net (R2D2)
+runs the same phases on a sequence replay (``make_recurrent_cycle``),
+where θ⁻ has a period of its own, ``target_cycles`` cycles.
+docs/architecture.md has the cycle timeline. Launchers construct this
+cycle through the ``concurrent`` / ``population`` entries of the
+``repro.api`` trainer registry (docs/experiment_api.md) rather than
+calling ``make_concurrent_cycle`` directly.
 """
 
 from __future__ import annotations
@@ -48,13 +50,16 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import DQNConfig
-from repro.core.dqn import make_update_fn
-from repro.core.replay import (ReplayState, per_flush_priorities, per_sample,
+from repro.core.dqn import (BURN_IN_SCOPE, UPDATE_SCOPE, burn_in,
+                            make_sequence_update_fn, make_update_fn)
+from repro.core.replay import (ReplayState, pack_frames,
+                               per_flush_priorities, per_sample,
                                per_stage_priorities, per_tree,
-                               replay_add_batch, replay_sample)
-from repro.core.synchronized import (ENV_SCOPE, POLICY_SCOPE, RENDER_SCOPE,
-                                     Obs, SamplerState, nstep_aggregate,
-                                     sync_round)
+                               replay_add_batch, replay_sample, sequence_add,
+                               sequence_layout, sequence_sample)
+from repro.core.synchronized import (ENV_SCOPE, LSTM_SCOPE, POLICY_SCOPE,
+                                     RENDER_SCOPE, Obs, SamplerState,
+                                     nstep_aggregate, sync_round)
 from repro.envs.games import EnvSpec
 from repro.optim.schedule import linear_epsilon
 
@@ -71,6 +76,9 @@ class TrainerCarry(NamedTuple):
     # stays bitwise-reproducible as a standalone run. Scalar int32; the
     # default keeps pre-population call sites working (replica 0).
     seed: jax.Array = 0
+    # θ⁻ of a recurrent net's cycle, which has a period of its own
+    # (``DQNConfig.target_cycles``); None where θ⁻ is θ at every boundary
+    target: Optional[Dict] = None
 
 
 def replica_key(tag: int, seed: jax.Array, step: jax.Array) -> jax.Array:
@@ -98,18 +106,32 @@ LEARN_SCOPE = "learn"
 """The trainer scan: all C/F minibatch updates."""
 SAMPLE_SCOPE = "sample"
 """One update's replay draw; under PER the tree descent and weights too."""
-UPDATE_SCOPE = "update"
-"""One update's forward, backward and optimizer step, and its priority
-staging under PER."""
 FLUSH_SCOPE = "flush"
 """The sync-point flush: staged priorities, n-step aggregation and the
 staged transitions into 𝒟."""
+RECURRENT_SCOPES = (f"{ACT_SCOPE}/{LSTM_SCOPE}",
+                    f"{LEARN_SCOPE}/{BURN_IN_SCOPE}",
+                    f"{LEARN_SCOPE}/{LSTM_SCOPE}")
+"""The scopes only a recurrent net's cycle has: the actor's LSTM step,
+the learner's burn-in unrolls and its recurrences (forward and
+backward)."""
 CYCLE_SCOPES = (
     ACT_SCOPE, f"{ACT_SCOPE}/{POLICY_SCOPE}", f"{ACT_SCOPE}/{ENV_SCOPE}",
     f"{ACT_SCOPE}/{RENDER_SCOPE}", PER_TREE_SCOPE, LEARN_SCOPE,
     f"{LEARN_SCOPE}/{SAMPLE_SCOPE}", f"{LEARN_SCOPE}/{UPDATE_SCOPE}",
-    FLUSH_SCOPE)
+    FLUSH_SCOPE) + RECURRENT_SCOPES
 """Every scope path of the cycle, nested scopes joined by ``/``."""
+
+
+def beta_at(variant, step: jax.Array) -> jax.Array:
+    """PER's importance exponent β at env step ``step``: annealed from
+    β0 to 1 over ``per_beta_anneal_steps``, or β0 throughout where that
+    is 0."""
+    if not variant.per_beta_anneal_steps:
+        return jnp.float32(variant.per_beta0)
+    return jnp.minimum(1.0, variant.per_beta0 + (1.0 - variant.per_beta0)
+                       * step.astype(jnp.float32)
+                       / variant.per_beta_anneal_steps)
 
 
 def make_concurrent_cycle(spec: EnvSpec, q_forward: Callable, opt,
@@ -123,6 +145,11 @@ def make_concurrent_cycle(spec: EnvSpec, q_forward: Callable, opt,
     head required by distributional variants. NoisyNet variants expect
     ``q_forward``/``q_logits`` to accept a trailing noise key.
     Returns cycle(carry) -> (carry', metrics)."""
+    if cfg.variant.recurrent:
+        return make_recurrent_cycle(spec, q_forward, opt, cfg, obs,
+                                    cycle_steps, kernel_backend)
+    assert cfg.target_cycles == 1, (
+        "θ⁻ has a period of its own only in the recurrent cycle")
     C = cycle_steps or cfg.target_update_period
     W = cfg.n_envs
     assert C % W == 0, (C, W)
@@ -178,10 +205,7 @@ def make_concurrent_cycle(spec: EnvSpec, q_forward: Callable, opt,
             # boundary, frozen for the whole training burst.
             with jax.named_scope(PER_TREE_SCOPE):
                 tree = per_tree(replay_snapshot)
-                beta = jnp.minimum(
-                    1.0, variant.per_beta0 + (1.0 - variant.per_beta0)
-                    * carry.step.astype(jnp.float32)
-                    / variant.per_beta_anneal_steps)
+                beta = beta_at(variant, carry.step)
 
             def train_body(tc, k):
                 params, opt_state, pending = tc
@@ -271,3 +295,142 @@ def prepopulate(spec: EnvSpec, q_forward: Callable, cfg: DQNConfig,
     agg = nstep_aggregate(staged, cfg.variant.n_step, cfg.discount)
     flat = {k: v.reshape((-1,) + v.shape[2:]) for k, v in agg.items()}
     return replay_add_batch(replay, flat), sampler
+
+
+APEX_ALPHA = 7.0
+"""The exponent spread of Ape-X's per-stream ε (Horgan et al. 2018)."""
+
+
+def actor_epsilons(variant, W: int) -> jax.Array:
+    """Ape-X's fixed per-stream exploration: ε_i = ε^(1 + α·i/(W-1))."""
+    i = jnp.arange(W, dtype=jnp.float32) / max(W - 1, 1)
+    return variant.actor_eps ** (1.0 + APEX_ALPHA * i)
+
+
+def act_periods(spec: EnvSpec, rq, params, sampler: SamplerState, eps_at,
+                periods: int, period: int, obs: Obs):
+    """``periods`` x ``period`` synchronized rounds of a recurrent net;
+    ``eps_at(i)`` is round i's ε (scalar or per stream). Returns the
+    sampler and the staged steps as the sequence replay stores them:
+    the newest frame of each step's stack, packed, its action, reward
+    and done ([R, W, ...]), and the LSTM state at each period's start
+    ([periods, W, lstm])."""
+    def round_body(s, i):
+        s, tr = sync_round(spec, rq, params, s, eps_at(i), obs)
+        return s, {"frames": pack_frames(tr["obs"][..., -1]),
+                   "action": tr["action"], "reward": tr["reward"],
+                   "done": tr["done"]}
+
+    def period_body(s, p):
+        start = {"h": s.core.h, "c": s.core.c}
+        s, steps = jax.lax.scan(round_body, s,
+                                p * period + jnp.arange(period))
+        return s, (steps, start)
+
+    sampler, (steps, starts) = jax.lax.scan(period_body, sampler,
+                                            jnp.arange(periods))
+    staged = {k: v.reshape((periods * period,) + v.shape[2:])
+              for k, v in steps.items()}
+    staged.update(starts)
+    return sampler, staged
+
+
+def sync_target(carry: TrainerCarry, target_cycles: int, C: int):
+    """θ⁻ for this cycle: refreshed from θ at the start of every
+    ``target_cycles``-th cycle, else the carry's."""
+    sync = (carry.step // C) % target_cycles == 0
+    return jax.tree.map(lambda p, t: jnp.where(sync, p, t), carry.params,
+                        carry.target)
+
+
+def make_recurrent_cycle(spec: EnvSpec, rq, opt, cfg: DQNConfig,
+                         obs: Obs = 84, cycle_steps: int = 0,
+                         kernel_backend: Optional[str] = None) -> Callable:
+    """The C-cycle of a recurrent net on a sequence replay (R2D2).
+
+    The same phases as the feed-forward cycle: C/W synchronized rounds
+    act from θ at the boundary, each stream carrying its LSTM state,
+    previous action and reward (``SamplerState.core``), and stage every
+    step's newest frame and the state at each sequence start; C/F
+    updates each draw a minibatch of sequences from the snapshot's
+    sum-tree, burn both nets in from the stored states without gradient
+    (``dqn.burn_in``) and step on the sequence loss against θ⁻; the
+    flush applies the staged sequence priorities and writes the staged
+    steps (``replay.sequence_add``). θ⁻ has a period of its own,
+    ``cfg.target_cycles`` cycles."""
+    C = cycle_steps or cfg.target_update_period
+    W = cfg.n_envs
+    v = cfg.variant
+    v.validate()
+    period = v.sequence_period
+    assert C % (W * period) == 0, (C, W, period)
+    periods = C // (W * period)
+    updates = max(C // cfg.train_period, 1)
+    layout = sequence_layout(cfg)
+    update_fn = make_sequence_update_fn(rq, opt, cfg)
+    stream_eps = actor_epsilons(v, W)
+
+    def cycle(carry: TrainerCarry) -> Tuple[TrainerCarry, Dict[str, jax.Array]]:
+        target_params = sync_target(carry, cfg.target_cycles, C)
+        snapshot = carry.replay
+        with jax.named_scope(ACT_SCOPE):
+            sampler, staged = act_periods(spec, rq, carry.params,
+                                          carry.sampler,
+                                          lambda i: stream_eps, periods,
+                                          period, obs)
+        with jax.named_scope(PER_TREE_SCOPE):
+            tree = per_tree(snapshot)
+            beta = beta_at(v, carry.step)
+
+        def train_body(tc, k):
+            params, opt_state, pending = tc
+            with jax.named_scope(SAMPLE_SCOPE):
+                batch = sequence_sample(snapshot, layout, k,
+                                        cfg.minibatch_size, beta, tree,
+                                        backend=kernel_backend)
+            with jax.named_scope(BURN_IN_SCOPE):
+                state_on, state_tg = burn_in(rq, params, target_params,
+                                             batch, v.burn_in)
+            params, opt_state, loss, priority = update_fn(
+                params, target_params, opt_state, batch, state_on, state_tg)
+            with jax.named_scope(UPDATE_SCOPE):
+                pending = per_stage_priorities(
+                    pending, batch["index"], priority, v.per_alpha,
+                    v.per_eps)
+            return (params, opt_state, pending), loss
+
+        with jax.named_scope(LEARN_SCOPE):
+            pending0 = jnp.zeros_like(snapshot["priority"])
+            (params, opt_state, pending), losses = jax.lax.scan(
+                train_body, (carry.params, carry.opt_state, pending0),
+                jax.random.split(replica_key(17, carry.seed, carry.step),
+                                 updates))
+
+        with jax.named_scope(FLUSH_SCOPE):
+            replay = per_flush_priorities(carry.replay, pending)
+            replay = sequence_add(replay, layout, staged)
+
+        metrics = {
+            "loss": jnp.mean(losses),
+            "reward": jnp.sum(staged["reward"]),
+            "episodes": jnp.sum(staged["done"]),
+            "eps": jnp.mean(stream_eps),
+        }
+        new = TrainerCarry(params, opt_state, replay, sampler,
+                           carry.step + C, carry.seed, target_params)
+        return new, metrics
+
+    return cycle
+
+
+def prepopulate_sequences(spec: EnvSpec, rq, params, cfg: DQNConfig,
+                          replay: ReplayState, sampler: SamplerState,
+                          n: int, obs: Obs = 84):
+    """Fill the sequence replay with n / W uniform-random steps per
+    stream (ε = 1). The net runs, so the stored LSTM states are those
+    of the initial parameters."""
+    W, period = cfg.n_envs, cfg.variant.sequence_period
+    sampler, staged = act_periods(spec, rq, params, sampler,
+                                  lambda i: jnp.float32(1.0),
+                                  n // (W * period), period, obs)
+    return sequence_add(replay, sequence_layout(cfg), staged), sampler

@@ -14,7 +14,8 @@ update, C51 swaps the Huber regression for a categorical cross-entropy
 against the projected target distribution (the ``categorical_projection``
 op), and NoisyNet threads per-call noise keys into the network.  With
 the default variant every formula below reduces to the vanilla path
-bit-for-bit.
+bit-for-bit. A recurrent variant (R2D2) has a loss of its own over
+replayed sequences: ``burn_in`` and ``sequence_loss``.
 """
 
 from __future__ import annotations
@@ -25,7 +26,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import DQNConfig, VariantConfig
+from repro.core.synchronized import LSTM_SCOPE
 from repro.kernels import ops as kops
+from repro.models.r2d2 import lstm_step, unroll
 
 
 def q_loss(params, target_params, batch: Dict[str, jax.Array],
@@ -193,5 +196,152 @@ def make_update_fn(q_forward: Callable, opt, cfg: DQNConfig,
         if variant is None:
             return new_params, opt_state, loss
         return new_params, opt_state, loss, td_abs
+
+    return update
+
+
+# ---------------------------------------------------------------------------
+# the recurrent (R2D2) sequence loss
+# ---------------------------------------------------------------------------
+
+UPDATE_SCOPE = "update"
+"""One update's forward, backward and optimizer step, and its priority
+staging under PER; in a sequence update, all but the recurrences."""
+BURN_IN_SCOPE = "burn_in"
+"""Both no-gradient burn-in unrolls of a sequence update, online and
+target, from the stored state."""
+
+
+def value_rescale(x: jax.Array, eps: float) -> jax.Array:
+    """h(x) = sign(x)(√(|x| + 1) − 1) + εx (Pohlen et al. 2018)."""
+    return jnp.sign(x) * (jnp.sqrt(jnp.abs(x) + 1.0) - 1.0) + eps * x
+
+
+def value_rescale_inv(x: jax.Array, eps: float) -> jax.Array:
+    """h⁻¹(x) = sign(x)((√(1 + 4ε(|x| + 1 + ε)) − 1) / 2ε)² − 1),
+    computed without its cancellations near 0: with u = √(|h⁻¹(x)| + 1),
+    the root of εu² + u = 1 + ε + |x|, |h⁻¹(x)| = (u − 1)(u + 1) and
+    u − 1 = |x| / (1 + ε(u + 1))."""
+    y = jnp.abs(x)
+    b = 1.0 + eps + y
+    u = 2.0 * b / (1.0 + jnp.sqrt(1.0 + 4.0 * eps * b))
+    return jnp.sign(x) * (y / (1.0 + eps * (u + 1.0))) * (u + 1.0)
+
+
+def _sequence_gates(rq, params, batch, lo: int, hi: int) -> jax.Array:
+    """The LSTM input projection of steps [lo, hi) of every sequence,
+    all at once: (hi - lo, B, 4H), time major."""
+    obs = batch["obs"][:, lo:hi]
+    B, T = obs.shape[:2]
+    gx = rq.embed(params, obs.reshape((B * T,) + obs.shape[2:]),
+                  batch["prev_action"][:, lo:hi].reshape(-1),
+                  batch["prev_reward"][:, lo:hi].reshape(-1))
+    return jnp.swapaxes(gx.reshape(B, T, -1), 0, 1)
+
+
+def burn_in(rq, params, target_params, batch, steps: int):
+    """Unroll the online and the target net over the first ``steps``
+    steps of each sequence from its stored state, without gradient;
+    returns both states after them."""
+    state = (batch["h"], batch["c"])
+    params = jax.lax.stop_gradient(params)
+    gx_on = _sequence_gates(rq, params, batch, 0, steps)
+    gx_tg = _sequence_gates(rq, target_params, batch, 0, steps)
+
+    def step(hc, xs):
+        gon, gtg, first = xs
+        hc = tuple(jnp.where(first[:, None], 0.0, x) for x in hc)
+        h_on, c_on = lstm_step(params, gon, hc[0], hc[1])
+        h_tg, c_tg = lstm_step(target_params, gtg, hc[2], hc[3])
+        return (h_on, c_on, h_tg, c_tg), None
+
+    hc, _ = jax.lax.scan(step, state + state,
+                         (gx_on, gx_tg, batch["first"][:, :steps].T))
+    hc = jax.lax.stop_gradient(hc)
+    return hc[:2], hc[2:]
+
+
+def sequence_loss(params, target_params, batch, state_on, state_tg, rq,
+                  cfg: DQNConfig, variant: VariantConfig):
+    """The R2D2 loss of a batch of sequences after their burn-in.
+
+    Both nets unroll the remaining steps from their burned-in states.
+    Each of the ``sequence_length`` trained steps t gets the n-step
+    double-Q target, under value rescaling h:
+
+        y_t = h(Σ_{k<n'} γᵏ r_{t+k} + γⁿ' h⁻¹(Q⁻(s_{t+n'}, argmax Q(s_{t+n'}))))
+
+    with the return cut at an episode's end (no bootstrap then) and at
+    the sequence's end (n' = min(n, steps left)). The loss is Σ_t ½δ_t²
+    per sequence, averaged over the batch with the importance weights;
+    the priority of a sequence is η·max_t|δ_t| + (1 − η)·mean_t|δ_t|.
+    Returns (loss, priorities)."""
+    L, m, n = variant.burn_in, variant.sequence_length, variant.n_step
+    eps = variant.value_rescale_eps
+    target_params = jax.lax.stop_gradient(target_params)
+    with jax.named_scope(UPDATE_SCOPE):
+        gx_on = _sequence_gates(rq, params, batch, L, L + m + 1)
+        gx_tg = _sequence_gates(rq, target_params, batch, L, L + m + 1)
+    first = batch["first"][:, L:].T
+    with jax.named_scope(LSTM_SCOPE):
+        hs_on, _ = unroll(params, gx_on, first, *state_on)
+        hs_tg, _ = unroll(target_params, gx_tg, first, *state_tg)
+    with jax.named_scope(UPDATE_SCOPE):
+        q_on = jnp.swapaxes(rq.head(params, hs_on), 0, 1)     # (B, m+1, A)
+        q_tg = jnp.swapaxes(rq.head(target_params, hs_tg), 0, 1)
+        reward = batch["reward"][:, L:L + m]
+        done = batch["done"][:, L:L + m].astype(reward.dtype)
+        action = batch["action"][:, L:L + m]
+        t = jnp.arange(m)
+        ret = jnp.zeros_like(reward)
+        live = jnp.ones_like(reward)
+        pad = ((0, 0), (0, n))
+        reward_p, done_p = jnp.pad(reward, pad), jnp.pad(done, pad)
+        for k in range(n):
+            inside = (t + k < m).astype(reward.dtype)
+            ret = ret + (cfg.discount ** k) * live * inside * \
+                reward_p[:, k:k + m]
+            live = live * (1.0 - inside * done_p[:, k:k + m])
+        steps = jnp.minimum(n, m - t)                          # n'
+        boot = t + steps
+        q_boot_on = jax.lax.stop_gradient(q_on)[:, boot]       # (B, m, A)
+        a_star = jnp.argmax(q_boot_on, axis=-1)
+        q_boot = jnp.take_along_axis(q_tg[:, boot], a_star[..., None],
+                                     axis=-1)[..., 0]
+        y = value_rescale(ret + live * (cfg.discount ** steps)
+                          * value_rescale_inv(q_boot, eps), eps)
+        qa = jnp.take_along_axis(q_on[:, :m], action[..., None],
+                                 axis=-1)[..., 0]
+        td = jax.lax.stop_gradient(y) - qa
+        per_seq = jnp.sum(0.5 * td * td, axis=1)
+        loss = jnp.mean(batch["weight"] * per_seq)
+        priority = sequence_priority(jax.lax.stop_gradient(td),
+                                     variant.priority_eta)
+    return loss, priority
+
+
+def sequence_priority(td: jax.Array, eta: float) -> jax.Array:
+    """(B, m) TD errors -> (B,) η·max|δ| + (1 − η)·mean|δ| (R2D2 §2.3)."""
+    abs_td = jnp.abs(td)
+    return eta * jnp.max(abs_td, axis=-1) + (1.0 - eta) * jnp.mean(
+        abs_td, axis=-1)
+
+
+def make_sequence_update_fn(rq, opt, cfg: DQNConfig):
+    """One sequence minibatch step after the burn-in:
+    (params, target, opt_state, batch, state_on, state_tg) ->
+    (params', opt_state', loss, priorities)."""
+    from repro.optim.base import apply_updates
+    variant = cfg.variant
+
+    def update(params, target_params, opt_state, batch, state_on, state_tg):
+        (loss, priority), grads = jax.value_and_grad(
+            sequence_loss, has_aux=True)(params, target_params, batch,
+                                         state_on, state_tg, rq, cfg,
+                                         variant)
+        with jax.named_scope(UPDATE_SCOPE):
+            updates, opt_state = opt.update(grads, opt_state, params)
+            params = apply_updates(params, updates)
+        return params, opt_state, loss, priority
 
     return update

@@ -45,8 +45,9 @@ import jax.numpy as jnp
 from repro.config import DQNConfig
 from repro.core.concurrent import (EVAL_STREAM_TAG, TrainerCarry,
                                    make_concurrent_cycle, prepopulate,
-                                   replica_key)
-from repro.core.replay import replay_init
+                                   prepopulate_sequences, replica_key)
+from repro.core.replay import (replay_init, sequence_layout,
+                               sequence_replay_init)
 from repro.core.synchronized import Obs, evaluate, sampler_init
 from repro.envs.games import EnvSpec
 from repro.envs.preprocess import as_obs
@@ -106,15 +107,26 @@ def make_replica_init(spec: EnvSpec, q_init_fn: Callable,
         seed = jnp.asarray(seed, jnp.int32)
         kinit, ksampler = jax.random.split(jax.random.PRNGKey(seed))
         params = q_init_fn(kinit)
-        replay = replay_init(
-            cfg.replay_capacity, pipe.shape + (cfg.frame_stack,),
-            obs_dtype=pipe.dtype,
-            prioritized=cfg.variant.prioritized)
-        sampler = sampler_init(spec, cfg, ksampler, pipe)
-        replay, sampler = prepopulate(spec, q_forward, cfg, replay, sampler,
-                                      cfg.prepopulate, pipe)
+        if cfg.variant.recurrent:
+            # q_forward is the recurrent net (models/r2d2.RecurrentQ)
+            replay = sequence_replay_init(sequence_layout(cfg), pipe.shape,
+                                          q_forward.cfg.lstm)
+            sampler = sampler_init(spec, cfg, ksampler, pipe,
+                                   core_cfg=q_forward.cfg)
+            replay, sampler = prepopulate_sequences(
+                spec, q_forward, params, cfg, replay, sampler,
+                cfg.prepopulate, pipe)
+        else:
+            replay = replay_init(
+                cfg.replay_capacity, pipe.shape + (cfg.frame_stack,),
+                obs_dtype=pipe.dtype,
+                prioritized=cfg.variant.prioritized)
+            sampler = sampler_init(spec, cfg, ksampler, pipe)
+            replay, sampler = prepopulate(spec, q_forward, cfg, replay,
+                                          sampler, cfg.prepopulate, pipe)
+        target = params if cfg.variant.recurrent else None
         return TrainerCarry(params, opt.init(params), replay, sampler,
-                            jnp.int32(0), seed)
+                            jnp.int32(0), seed, target)
 
     return init_one
 

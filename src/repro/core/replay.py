@@ -20,8 +20,10 @@ order-independent reduction), keeping the flush deterministic even when
 one slot is sampled by several minibatches.
 
 Transitions are stored as full (obs, action, reward, next_obs, done)
-records. Storage dtype for observations is uint8 (the paper's 1-byte
-pixel economy).
+records; a recurrent learner's sequence replay (R2D2) stores one frame
+per step of each stream instead (``sequence_replay_init``, below).
+Storage dtype for observations is uint8 (the paper's 1-byte pixel
+economy).
 
 How a frame stack is stored follows its shape and dtype alone. A uint8
 stack of at least one lane row (128 32-bit words, 512 bytes) is packed
@@ -47,7 +49,7 @@ staging/flush timeline is diagrammed in docs/architecture.md.
 from __future__ import annotations
 
 import math
-from typing import Dict, Optional, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +62,8 @@ __all__ = [
     "replay_capacity", "replay_size", "replay_is_prioritized",
     "replay_add_batch", "replay_sample", "per_tree", "stratified_indices",
     "per_sample", "per_stage_priorities", "per_flush_priorities",
+    "SequenceLayout", "sequence_layout", "sequence_replay_init",
+    "sequence_add", "sequence_sample", "sequence_gather", "pack_frames",
 ]
 
 ReplayState = Dict[str, jax.Array]
@@ -229,6 +233,14 @@ def per_tree(state: ReplayState) -> jax.Array:
     return tree_build(state["priority"])
 
 
+def _stratified_draw(tree: jax.Array, key: jax.Array, n: int,
+                     backend: Optional[str] = None) -> jax.Array:
+    total = tree[1]
+    u = jax.random.uniform(key, (n,))
+    targets = (jnp.arange(n, dtype=jnp.float32) + u) / n * total
+    return kops.segment_tree_sample(tree, targets, backend=backend)
+
+
 def stratified_indices(tree: jax.Array, key: jax.Array, n: int,
                        size: jax.Array,
                        backend: Optional[str] = None) -> jax.Array:
@@ -238,10 +250,7 @@ def stratified_indices(tree: jax.Array, key: jax.Array, n: int,
     filled prefix [0, max(size, 1)) — zero-mass leaves are unreachable
     except at exact CDF boundaries (measure-zero), where the clamp
     applies. Shared by ``per_sample`` and the disaggregated learner."""
-    total = tree[1]
-    u = jax.random.uniform(key, (n,))
-    targets = (jnp.arange(n, dtype=jnp.float32) + u) / n * total
-    idx = kops.segment_tree_sample(tree, targets, backend=backend)
+    idx = _stratified_draw(tree, key, n, backend)
     return jnp.minimum(idx, jnp.maximum(size, 1) - 1)
 
 
@@ -293,3 +302,213 @@ def per_flush_priorities(state: ReplayState, pending: jax.Array) -> ReplayState:
     new["priority"] = jnp.where(pending > 0, pending, state["priority"])
     new["max_priority"] = jnp.maximum(state["max_priority"], jnp.max(pending))
     return new
+
+
+# ---------------------------------------------------------------------------
+# sequence replay (R2D2): per-stream time rings of single frames
+# ---------------------------------------------------------------------------
+#
+# A recurrent learner replays sequences of ``span`` consecutive steps of
+# one stream, starting every ``period`` steps, and unrolls its network
+# from the recurrent state the actor had at the start. Each stream keeps
+# a time ring of ``ring = slots * period`` steps: per step the newest
+# frame of the actor's stack (packed lane-aligned, ``frame_words``), the
+# action, the reward and the done flag. Each of the ``slots`` sequence
+# starts of a stream is one leaf of the sum-tree (leaf = stream * slots
+# + slot), which also holds the stored LSTM state. A step's frame stack
+# is rebuilt at sampling from the stream's own preceding frames, masked
+# where an episode started as ``reset_stack_where`` masks the actor's,
+# so the ring holds one frame per step, not two stacks.
+#
+# A leaf can be drawn while its whole window is in the ring: the
+# ``history`` frames before its start (the stack of its first step) and
+# the ``span`` steps from it. The ring's unwritten tail reads as zero
+# frames and no episode end, which is what the actor's stack held before
+# its first step, so the sequences at a stream's start are whole too.
+
+
+class SequenceLayout(NamedTuple):
+    streams: int      # W
+    slots: int        # sequence starts (leaves) a stream keeps
+    period: int       # steps between a stream's sequence starts
+    span: int         # steps one sequence covers
+    history: int      # frames before a step that its stack reads
+
+    @property
+    def ring(self) -> int:
+        return self.slots * self.period
+
+    @property
+    def capacity(self) -> int:
+        return self.streams * self.slots
+
+    @property
+    def window(self) -> int:
+        return self.history + self.span
+
+
+def sequence_layout(cfg) -> SequenceLayout:
+    """The layout a ``DQNConfig`` with a recurrent variant implies;
+    ``replay_capacity`` counts sequences."""
+    v = cfg.variant
+    return SequenceLayout(cfg.n_envs, cfg.replay_capacity // cfg.n_envs,
+                          v.sequence_period,
+                          v.burn_in + v.sequence_length + 1,
+                          cfg.frame_stack - 1)
+
+
+def pack_frames(frames: jax.Array) -> jax.Array:
+    """u8[..., H, W] frames -> u32[..., words], lane-aligned rows; a
+    frame under one lane row stays as it is (``frame_words``)."""
+    width = frame_words(frames.shape[-2:], frames.dtype)
+    if not width:
+        return frames
+    lead = frames.shape[:-2]
+    words = _pack(frames.reshape((-1,) + frames.shape[-2:]), width)
+    return words.reshape(lead + words.shape[-1:])
+
+
+def sequence_replay_init(layout: SequenceLayout, frame_shape: Tuple[int, int],
+                         lstm: int) -> ReplayState:
+    rows = layout.streams * layout.ring
+    width = frame_words(tuple(frame_shape), jnp.uint8)
+    frames = (jnp.zeros((rows, width), jnp.uint32) if width
+              else jnp.zeros((rows,) + tuple(frame_shape), jnp.uint8))
+    return {
+        "frames": frames,
+        "action": jnp.zeros((rows,), jnp.int32),
+        "reward": jnp.zeros((rows,), jnp.float32),
+        "done": jnp.zeros((rows,), jnp.bool_),
+        "frame_like": jnp.zeros((0,) + tuple(frame_shape), jnp.uint8),
+        "h": jnp.zeros((layout.capacity, lstm), jnp.float32),
+        "c": jnp.zeros((layout.capacity, lstm), jnp.float32),
+        "priority": jnp.zeros((next_pow2(layout.capacity),), jnp.float32),
+        "max_priority": jnp.ones((), jnp.float32),
+        "steps": jnp.zeros((), jnp.int32),    # steps each stream wrote
+        "size": jnp.zeros((), jnp.int32),     # leaves that can be drawn
+    }
+
+
+def _slot_starts(layout: SequenceLayout, steps: jax.Array) -> jax.Array:
+    """(slots,) the stream step at which the sequence in each slot
+    starts, once ``steps`` steps are written (-1: none yet)."""
+    first = jnp.arange(layout.slots, dtype=jnp.int32) * layout.period
+    latest = first + layout.ring * ((steps - 1 - first) // layout.ring)
+    return jnp.where(steps > first, latest, -1)
+
+
+def _drawable(layout: SequenceLayout, starts: jax.Array,
+              steps: jax.Array) -> jax.Array:
+    """Which starts have their whole window in the ring."""
+    return ((starts >= 0) & (starts + layout.span <= steps)
+            & (starts - layout.history >= steps - layout.ring))
+
+
+def sequence_add(state: ReplayState, layout: SequenceLayout,
+                 staged: Dict[str, jax.Array]) -> ReplayState:
+    """Write R staged steps of every stream (R a whole number of
+    periods): ``frames`` u32[R, W, words], ``action``/``reward``/``done``
+    [R, W], and the LSTM state at each period's start, ``h``/``c``
+    [R / period, W, lstm]. Leaves whose window became whole enter at
+    ``max_priority``, leaves whose window was overwritten leave at 0,
+    and the others keep their priority."""
+    R, W = staged["action"].shape
+    assert R % layout.period == 0 and W == layout.streams, (R, W, layout)
+    t0 = state["steps"]
+    t1 = t0 + R
+    pos = (t0 + jnp.arange(R, dtype=jnp.int32)) % layout.ring
+    rows = (jnp.arange(W, dtype=jnp.int32)[None, :] * layout.ring
+            + pos[:, None]).reshape(-1)
+    new = dict(state)
+    new["frames"] = state["frames"].at[rows].set(
+        staged["frames"].reshape((R * W,) + state["frames"].shape[1:]))
+    for k in ("action", "reward", "done"):
+        new[k] = state[k].at[rows].set(
+            staged[k].reshape(-1).astype(state[k].dtype))
+    slot = ((t0 // layout.period + jnp.arange(R // layout.period,
+                                              dtype=jnp.int32))
+            % layout.slots)
+    leaves = (jnp.arange(W, dtype=jnp.int32)[None, :] * layout.slots
+              + slot[:, None]).reshape(-1)
+    for k in ("h", "c"):
+        new[k] = state[k].at[leaves].set(
+            staged[k].reshape(leaves.shape[0], -1))
+    s0, s1 = _slot_starts(layout, t0), _slot_starts(layout, t1)
+    ok0, ok1 = _drawable(layout, s0, t0), _drawable(layout, s1, t1)
+    kept = jnp.tile(ok0 & (s0 == s1), W)
+    fresh = jnp.tile(ok1, W)
+    pad = state["priority"].shape[0] - layout.capacity
+    kept, fresh = (jnp.pad(x, (0, pad)) for x in (kept, fresh))
+    new["priority"] = jnp.where(
+        fresh, jnp.where(kept, state["priority"], state["max_priority"]),
+        0.0)
+    new["steps"] = t1
+    new["size"] = W * jnp.sum(ok1.astype(jnp.int32))
+    return new
+
+
+def sequence_sample(state: ReplayState, layout: SequenceLayout,
+                    key: jax.Array, n: int, beta: jax.Array, tree: jax.Array,
+                    backend: Optional[str] = None) -> Dict[str, jax.Array]:
+    """n sequences by stratified proportional sampling over the leaves,
+    with the stacks, inputs and targets of each of their ``span`` steps:
+
+    * ``obs`` u8[n, span, H, W, K]: the stacks the actor saw;
+    * ``prev_action``/``prev_reward`` [n, span]: the recurrent net's
+      inputs, zero at an episode's first step; ``first`` [n, span]:
+      the steps that start an episode (the state is zeroed there);
+    * ``action``/``reward``/``done`` [n, span]: each step's own;
+    * ``h``/``c`` [n, lstm]: the stored state at the first step;
+    * ``index`` (the leaves) and ``weight`` (the normalized importance
+      weights, as ``per_sample``'s)."""
+    total = tree[1]
+    idx = jnp.minimum(_stratified_draw(tree, key, n, backend),
+                      layout.capacity - 1)
+    size = jnp.maximum(state["size"], 1)
+    probs = jnp.maximum(state["priority"][idx] / jnp.maximum(total, 1e-30),
+                        1e-30)
+    w = (size.astype(jnp.float32) * probs) ** (-beta)
+    out = sequence_gather(state, layout, idx)
+    out["index"] = idx
+    out["weight"] = w / jnp.maximum(jnp.max(w), 1e-30)
+    return out
+
+
+def sequence_gather(state: ReplayState, layout: SequenceLayout,
+                    idx: jax.Array) -> Dict[str, jax.Array]:
+    """The sequences of leaves ``idx`` (``sequence_sample``'s fields but
+    ``index`` and ``weight``)."""
+    n = idx.shape[0]
+    stream, slot = idx // layout.slots, idx % layout.slots
+    start = _slot_starts(layout, state["steps"])[slot]
+    pos = (start[:, None] - layout.history
+           + jnp.arange(layout.window, dtype=jnp.int32)[None, :]) % layout.ring
+    rows = stream[:, None] * layout.ring + pos              # (n, window)
+    like = state["frame_like"]
+    frames = state["frames"][rows.reshape(-1)]
+    if frames.dtype != like.dtype:
+        frames = _unpack(frames, like.shape[1:])
+    frames = frames.reshape(rows.shape + like.shape[1:])
+    action, reward, done = (state[k][rows] for k in ("action", "reward",
+                                                     "done"))
+    H, T = layout.history, layout.span
+    # frame m of the window is step start - H + m; step k's stack holds
+    # frames k..k+H, and frame k+i is kept while no episode starts
+    # after it: an end at any of frames k+i..k+H-1
+    planes, keep = [frames[:, H:H + T]], jnp.ones((n, T), jnp.bool_)
+    for i in range(H - 1, -1, -1):
+        keep = keep & ~done[:, i:i + T]
+        planes.insert(0, jnp.where(keep[..., None, None],
+                                   frames[:, i:i + T], 0))
+    first = done[:, H - 1:H - 1 + T]
+    return {
+        "obs": jnp.stack(planes, axis=-1),
+        "prev_action": jnp.where(first, 0, action[:, H - 1:H - 1 + T]),
+        "prev_reward": jnp.where(first, 0.0, reward[:, H - 1:H - 1 + T]),
+        "first": first,
+        "action": action[:, H:H + T],
+        "reward": reward[:, H:H + T],
+        "done": done[:, H:H + T],
+        "h": state["h"][idx],
+        "c": state["c"][idx],
+    }

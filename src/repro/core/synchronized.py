@@ -14,7 +14,7 @@ concurrent.py) is the sampler loop of Algorithm 1.
 
 from __future__ import annotations
 
-from typing import Callable, Dict, NamedTuple, Tuple, Union
+from typing import Callable, Dict, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -23,7 +23,8 @@ from repro.config import DQNConfig
 from repro.envs.games import EnvSpec, step_autoreset
 from repro.envs.preprocess import (ObsPipeline, as_obs, init_obs_stack,
                                    obs_batch, push_frame, reset_stack_where)
-from repro.core.policy import policy_step, stream_keys
+from repro.core.policy import egreedy_stream, policy_step, stream_keys
+from repro.models.r2d2 import Core, RecurrentQ, reset_core, zero_core
 
 # ``obs`` arguments below accept a plain int (legacy pixel frame size)
 # or an ObsPipeline (pixels | vector) — see envs/preprocess.py.
@@ -39,23 +40,33 @@ ENV_SCOPE = "env"
 """The vmapped env dynamics (``step_autoreset``)."""
 RENDER_SCOPE = "render"
 """The observation render of the stepped envs (``obs_batch``)."""
+LSTM_SCOPE = "lstm"
+"""A recurrent net's recurrence: the actor's LSTM step inside ``act``,
+the learner's unrolls inside ``learn`` (``core/dqn.py``)."""
 
 
 class SamplerState(NamedTuple):
     env_states: Dict[str, jax.Array]   # vmapped env states (leading W)
     stack: jax.Array                   # (W, *obs, K) — current obs stack
     key: jax.Array
+    # a recurrent net's per-stream input to the next step (LSTM state,
+    # previous action and reward, zero at episode starts); None for a
+    # feed-forward net
+    core: Optional[Core] = None
 
 
 def sampler_init(spec: EnvSpec, cfg: DQNConfig, key: jax.Array,
-                 obs: Obs = 84) -> SamplerState:
+                 obs: Obs = 84, core_cfg=None) -> SamplerState:
+    """``core_cfg``: a recurrent net's configuration (its zero ``Core``
+    per stream), or None."""
     pipe = as_obs(obs)
     kreset, kstate = jax.random.split(key)
     env_states = jax.vmap(spec.reset)(jax.random.split(kreset, cfg.n_envs))
     stack = init_obs_stack(cfg.n_envs, pipe, cfg.frame_stack)
     frame = obs_batch(pipe, spec, env_states)
     stack = push_frame(stack, frame)
-    return SamplerState(env_states, stack, kstate)
+    core = None if core_cfg is None else zero_core(cfg.n_envs, core_cfg)
+    return SamplerState(env_states, stack, kstate, core)
 
 
 def sync_round(spec: EnvSpec, q_forward: Callable, params,
@@ -68,12 +79,26 @@ def sync_round(spec: EnvSpec, q_forward: Callable, params,
     key, kact, kstep = jax.random.split(s.key, 3)
     cur = s.stack                                           # (W, *obs, K)
     W = cur.shape[0]
-    # ONE batched Q call + per-stream ε draws — the same stateless
-    # primitive the serving layer batches client streams through
-    # (core/policy.py), so served actions match these bitwise.
-    with jax.named_scope(POLICY_SCOPE):
-        actions = policy_step(q_forward, params, cur, eps,
-                              stream_keys(kact, W))
+    if s.core is None:
+        # ONE batched Q call + per-stream ε draws — the same stateless
+        # primitive the serving layer batches client streams through
+        # (core/policy.py), so served actions match these bitwise.
+        with jax.named_scope(POLICY_SCOPE):
+            actions = policy_step(q_forward, params, cur, eps,
+                                  stream_keys(kact, W))
+    else:
+        # a recurrent net (models/r2d2.RecurrentQ): the same one batched
+        # step, its recurrence under a scope of its own
+        rq, core = q_forward, s.core
+        with jax.named_scope(POLICY_SCOPE):
+            gates_x = rq.embed(params, cur, core.prev_action,
+                               core.prev_reward)
+        with jax.named_scope(LSTM_SCOPE):
+            h, c = rq.step(params, gates_x, core.h, core.c)
+        with jax.named_scope(POLICY_SCOPE):
+            q = rq.head(params, h)
+            eps = jnp.broadcast_to(jnp.asarray(eps, jnp.float32), (W,))
+            actions = jax.vmap(egreedy_stream)(q, eps, stream_keys(kact, W))
     with jax.named_scope(ENV_SCOPE):
         env_states, rewards, dones = jax.vmap(
             lambda st, a, k: step_autoreset(spec, st, a, k)
@@ -84,7 +109,10 @@ def sync_round(spec: EnvSpec, q_forward: Callable, params,
     new_stack = push_frame(reset_stack_where(s.stack, dones), frame)
     transitions = {"obs": cur, "action": actions, "reward": rewards,
                    "next_obs": next_obs, "done": dones}
-    return SamplerState(env_states, new_stack, key), transitions
+    if s.core is None:
+        return SamplerState(env_states, new_stack, key), transitions
+    core = reset_core(Core(h, c, actions, rewards), dones)
+    return SamplerState(env_states, new_stack, key, core), transitions
 
 
 def nstep_aggregate(staged: Dict[str, jax.Array], n: int,
@@ -146,7 +174,10 @@ def evaluate(spec: EnvSpec, q_forward: Callable, params, key: jax.Array,
     env_states = jax.vmap(spec.reset)(jax.random.split(kinit, n_episodes))
     stack = init_obs_stack(n_episodes, pipe, cfg.frame_stack)
     stack = push_frame(stack, obs_batch(pipe, spec, env_states))
-    s = SamplerState(env_states, stack, krun)
+    # a recurrent net starts each stream at zero
+    core = (zero_core(n_episodes, q_forward.cfg)
+            if isinstance(q_forward, RecurrentQ) else None)
+    s = SamplerState(env_states, stack, krun, core)
 
     def body(carry, _):
         s, ret, live = carry

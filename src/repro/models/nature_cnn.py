@@ -47,22 +47,41 @@ def _linear_spec(spec: Dict[str, Any], name: str, d_in: int, d_out: int,
                                          value=sigma)
 
 
+def conv_param_spec(spec: Dict[str, Any], convs, frame_size: int,
+                    frame_stack: int) -> int:
+    """The conv torso's leaves (``conv{i}_w``/``conv{i}_b``) into
+    ``spec``; returns the width of its flattened output."""
+    in_ch = frame_stack
+    size = frame_size
+    for i, (out_ch, k, s) in enumerate(convs):
+        spec[f"conv{i}_w"] = P.Leaf((k, k, in_ch, out_ch),
+                                    (None, None, None, "mlp"),
+                                    fan_in=k * k * in_ch)
+        spec[f"conv{i}_b"] = P.Leaf((out_ch,), ("mlp",), init="zeros")
+        size = (size - k) // s + 1
+        in_ch = out_ch
+    return size * size * in_ch
+
+
+def conv_torso(params, frames: jax.Array, convs, cdt) -> jax.Array:
+    """(B, H, W, C) uint8 frames -> (B, flat) ReLU conv features."""
+    x = frames.astype(cdt) / jnp.asarray(255.0, cdt)
+    for i, (_, k, s) in enumerate(convs):
+        x = jax.lax.conv_general_dilated(
+            x, params[f"conv{i}_w"].astype(cdt), window_strides=(s, s),
+            padding="VALID", dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        x = jax.nn.relu(x + params[f"conv{i}_b"].astype(cdt))
+    return x.reshape(x.shape[0], -1)
+
+
 def q_param_spec(cfg: NatureCNNConfig, n_actions: int) -> Dict[str, Any]:
     spec: Dict[str, Any] = {}
     if cfg.vector_dim:
         # vector mode: fc-only trunk on the stacked state vectors
         flat = cfg.vector_dim * cfg.frame_stack
     else:
-        in_ch = cfg.frame_stack
-        size = cfg.frame_size
-        for i, (out_ch, k, s) in enumerate(cfg.convs):
-            spec[f"conv{i}_w"] = P.Leaf((k, k, in_ch, out_ch),
-                                        (None, None, None, "mlp"),
-                                        fan_in=k * k * in_ch)
-            spec[f"conv{i}_b"] = P.Leaf((out_ch,), ("mlp",), init="zeros")
-            size = (size - k) // s + 1
-            in_ch = out_ch
-        flat = size * size * in_ch
+        flat = conv_param_spec(spec, cfg.convs, cfg.frame_size,
+                               cfg.frame_stack)
     K = cfg.num_atoms
     spec["fc_w"] = P.Leaf((flat, cfg.hidden), (None, "mlp"), fan_in=flat)
     spec["fc_b"] = P.Leaf((cfg.hidden,), ("mlp",), init="zeros")
@@ -104,13 +123,7 @@ def _trunk(params, frames: jax.Array, cfg: NatureCNNConfig, cdt,
         # (B, D, K) float32 state vectors, already in [0, 1] — no /255
         x = frames.astype(cdt).reshape(frames.shape[0], -1)
     else:
-        x = frames.astype(cdt) / jnp.asarray(255.0, cdt)
-        for i, (_, k, s) in enumerate(cfg.convs):
-            x = jax.lax.conv_general_dilated(
-                x, params[f"conv{i}_w"].astype(cdt), window_strides=(s, s),
-                padding="VALID", dimension_numbers=("NHWC", "HWIO", "NHWC"))
-            x = jax.nn.relu(x + params[f"conv{i}_b"].astype(cdt))
-        x = x.reshape(x.shape[0], -1)
+        x = conv_torso(params, frames, cfg.convs, cdt)
     kfc = jax.random.fold_in(noise_key, 0) if noise_key is not None else None
     return jax.nn.relu(_affine(params, "fc", x, cfg, cdt, kfc))
 

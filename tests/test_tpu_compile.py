@@ -1,5 +1,5 @@
 """Compiles for a described TPU v5e: the main path's Pallas kernels and
-the dqn and rainbow population cycles at the Nature geometry.
+the dqn, rainbow and r2d2 population cycles at their cells' sizes.
 
 Nothing runs here. The TPU compiler that ships with libtpu compiles for
 a chip that is described, not attached, so a kernel Mosaic would refuse
@@ -89,9 +89,10 @@ def test_categorical_projection_compiles_for_v5e(one_chip, vmapped):
 HLO_INSTR = re.compile(r"%([\w.-]+) = (\w+\[[\d,]*\])(\{[^}]*\})? "
                        r"([\w-]+)\(%?([\w.-]*)")
 REPLAY_FRAMES = re.compile(r"replay\[\\'(next_)?obs\\'\]")
+SEQUENCE_FRAMES = re.compile(r"replay\[\\'frames\\'\]")
 
 
-def frame_relayouts(hlo_text):
+def frame_relayouts(hlo_text, stores=REPLAY_FRAMES):
     """The replay frame stores' shapes among ENTRY's parameters, and the
     ``copy`` instructions of ENTRY that give such a store another layout
     than their operand has."""
@@ -103,7 +104,7 @@ def frame_relayouts(hlo_text):
             continue
         name, shape, layout, opcode, operand = m.groups()
         layouts[name] = layout
-        if opcode == "parameter" and REPLAY_FRAMES.search(line):
+        if opcode == "parameter" and stores.search(line):
             frames.add(shape)
         elif opcode == "copy":
             copies.append((name, shape, layout, operand))
@@ -124,7 +125,8 @@ def _check_population_cycle(one_chip, monkeypatch, variant):
 
     from repro.api import ExperimentSpec, build_trainer
     from repro.configs.dqn_nature import get_variant
-    from repro.core.concurrent import CYCLE_SCOPES, PER_TREE_SCOPE
+    from repro.core.concurrent import (CYCLE_SCOPES, PER_TREE_SCOPE,
+                                       RECURRENT_SCOPES)
 
     monkeypatch.setenv("REPRO_KERNEL_BACKEND", "mosaic")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -139,7 +141,7 @@ def _check_population_cycle(one_chip, monkeypatch, variant):
                          trainer.init_template())
     compiled = trainer.cycle.lower(carry).compile()
     text = compiled.as_text()
-    want = set(CYCLE_SCOPES)
+    want = set(CYCLE_SCOPES) - set(RECURRENT_SCOPES)
     if variant == "dqn":
         want.discard(PER_TREE_SCOPE)
     else:
@@ -162,3 +164,42 @@ def test_rainbow_population_cycle_compiles_for_v5e(one_chip, monkeypatch):
 
 def test_dqn_population_cycle_compiles_for_v5e(one_chip, monkeypatch):
     _check_population_cycle(one_chip, monkeypatch, "dqn")
+
+
+def test_r2d2_population_cycle_compiles_for_v5e(one_chip, monkeypatch):
+    """The r2d2-nature.w256 cell's cycle (W=256, 16,384 sequence leaves,
+    84x84 frames), with the segment_tree kernel at its leaf count, fits
+    one v5e; every scope of the cycle, the recurrent ones included,
+    survives into its op_name metadata; and the sequence replay's frame
+    store, one lane-aligned row per step, keeps its entry layout."""
+    import json
+
+    from test_concurrent import scopes_found
+
+    from repro.api import ExperimentSpec, build_trainer
+    from repro.core.concurrent import CYCLE_SCOPES
+
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "mosaic")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "bench", "configs",
+                           "r2d2-nature.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, "bench", "traffic", "w256.json")) as f:
+        traffic = json.load(f)
+    spec = ExperimentSpec.from_dict(dict(
+        cfg["spec"], env=traffic["env"], envs=traffic["envs"], seeds=1))
+    trainer = build_trainer(spec)
+    carry = jax.tree.map(lambda s: _shape(one_chip, s.shape, s.dtype),
+                         trainer.init_template())
+    compiled = trainer.cycle.lower(carry).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert scopes_found(text) == set(CYCLE_SCOPES)
+    frames, relayouts = frame_relayouts(text, SEQUENCE_FRAMES)
+    assert frames == {"u32[1,655360,1792]"}
+    assert not relayouts, relayouts
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 3e9, mem.temp_size_in_bytes
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < V5E_HBM_BYTES, total
